@@ -27,7 +27,7 @@ from concurrent.futures import ProcessPoolExecutor
 
 from repro.core.cluster import ClusterSimulator, ClusterSpec, NodeSpec
 
-from benchmarks.common import save_json, table
+from benchmarks.common import SPAWN, save_json, table
 
 #: `single` is the parity anchor, not a fleet policy — swept separately
 #: in the goldens; the bench compares the five real strategies.
@@ -89,7 +89,7 @@ def run(quick: bool = False) -> dict:
             for pat in patterns for n in sizes for pol in POLICIES]
     workers = min(os.cpu_count() or 1, len(jobs))
     t0 = time.time()
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with ProcessPoolExecutor(max_workers=workers, mp_context=SPAWN) as pool:
         cells = list(pool.map(_cell, jobs))
     wall = time.time() - t0
 

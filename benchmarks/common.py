@@ -8,10 +8,16 @@ should get a clean signal, not a raw ``FileNotFoundError`` traceback.
 from __future__ import annotations
 
 import json
+import multiprocessing
 import os
 from typing import Any
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
+
+#: context for the DES worker pools: each worker starts from a fresh
+#: interpreter, because a parent that has touched JAX holds the
+#: accelerator and a forked child would inherit that state
+SPAWN = multiprocessing.get_context("spawn")
 
 
 def result_path(name: str) -> str:

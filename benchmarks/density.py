@@ -32,7 +32,7 @@ from concurrent.futures import ProcessPoolExecutor
 from repro.core.des import DensitySimulator, find_density
 from repro.core.plan import SYSTEMS
 
-from benchmarks.common import pct, save_json, table
+from benchmarks.common import SPAWN, pct, save_json, table
 
 # + nexus-prefetch-only: same fetch overlap as nexus-async but no early
 # release — its density gap vs nexus-async isolates §4.2.5's VM-holding
@@ -72,7 +72,7 @@ def run(quick: bool = False) -> dict:
             for s in ALL_SYSTEMS for seed in SEEDS for pat in patterns]
     workers = min(os.cpu_count() or 1, len(jobs))
     t0 = time.time()
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with ProcessPoolExecutor(max_workers=workers, mp_context=SPAWN) as pool:
         found = list(pool.map(_search, jobs))
     sweep_wall = time.time() - t0
 
@@ -81,7 +81,7 @@ def run(quick: bool = False) -> dict:
     # returned densities must MATCH the exact matrix cell-for-cell.
     fjobs = [j[:-1] + (True,) for j in jobs]
     t0 = time.time()
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with ProcessPoolExecutor(max_workers=workers, mp_context=SPAWN) as pool:
         fast_found = list(pool.map(_search, fjobs))
     fast_wall = time.time() - t0
 

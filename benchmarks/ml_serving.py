@@ -25,7 +25,7 @@ from repro.core.des import DensitySimulator, find_density
 from repro.core.plan import SYSTEMS, compile_plan, phase_durations
 from repro.core.workloads import ML_SCENARIO_NAMES, ml_suite
 
-from benchmarks.common import pct, save_json, table
+from benchmarks.common import SPAWN, pct, save_json, table
 
 SYSTEMS_ORDER = ("baseline", "nexus-tcp", "nexus-prefetch-only",
                  "nexus-async", "nexus", "nexus-sdk-only", "wasm")
@@ -191,7 +191,7 @@ def run(quick: bool = False) -> dict:
         duration = 40.0
         jobs = [(s, duration) for s in SYSTEMS_ORDER]
         workers = min(os.cpu_count() or 1, len(jobs))
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        with ProcessPoolExecutor(max_workers=workers, mp_context=SPAWN) as pool:
             density_rows = list(pool.map(_search, jobs))
         base = max(density_rows[0]["density"], 1)
         for r in density_rows:

@@ -22,6 +22,9 @@ def main() -> None:
                     help="comma-separated subset of benchmark names")
     args = ap.parse_args()
 
+    from repro.models import compile_cache
+    compile_cache.enable()
+
     from benchmarks import (cache, cluster, cold_start, cpu_cycles,
                             density, faasm_gap, fault_tolerance,
                             hlo_analysis, memory_footprint, ml_serving,

@@ -49,7 +49,7 @@ from repro.core.des import (DensitySimulator, bundle_cache_stats,
                             find_density)
 from repro.core.plan import SYSTEMS
 
-from benchmarks.common import save_json, table
+from benchmarks.common import SPAWN, save_json, table
 
 TARGET_HOT_SPEEDUP = 2.0        # single-core: hot vs classic, nexus config
 N_FUNCTIONS = 400
@@ -169,7 +169,7 @@ def run(quick: bool = False) -> dict:
             for s in systems for sd in seeds]
     workers = min(os.cpu_count() or 1, len(jobs))
     t0 = time.perf_counter()
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with ProcessPoolExecutor(max_workers=workers, mp_context=SPAWN) as pool:
         done = list(pool.map(_sweep_job, jobs))
     new_wall = time.perf_counter() - t0
     new_inv = sum(c for c, _ in done)

@@ -15,6 +15,14 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
 )
 
+# One TPU v5e chip's share of granite-8b served as a four-stage pipeline
+# over four chips: every width above as published, depth cut
+# ``num_layers`` 36 -> 9 (this chip's stage; the other 27 layers would
+# lie on the next three chips). 4.33 GB of bf16 weights, random from a
+# fixed seed (no checkpoint). Serves at the `full` shapes: prefill
+# (1, 2048), decode (8, 2048), encode (32, 512).
+CHIP = CONFIG.replace(name="granite-8b-9l", num_layers=9)
+
 SMOKE = CONFIG.replace(
     name="granite-8b-smoke",
     num_layers=2,

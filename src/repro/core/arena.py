@@ -151,10 +151,14 @@ class ArenaRegistry:
         self._lock = threading.Lock()
         self._capacity_mb = capacity_mb
 
-    def get(self, tenant: str) -> TenantArena:
+    def get(self, tenant: str, min_mb: float = 0.0) -> TenantArena:
+        """The tenant's arena, created on first use with the registry's
+        capacity or `min_mb`, whichever is larger (a deployment sizes
+        it to its own payloads); an existing arena keeps its size."""
         with self._lock:
             if tenant not in self._arenas:
-                self._arenas[tenant] = TenantArena(tenant, self._capacity_mb)
+                self._arenas[tenant] = TenantArena(
+                    tenant, max(self._capacity_mb, min_mb))
             return self._arenas[tenant]
 
     def resolve(self, tenant: str, slot: Slot) -> Slot:

@@ -134,12 +134,14 @@ class NexusBackend:
 
     # ------------------------------------------------------ registration
 
-    def register_function(self, function: str, buckets: set[str]) -> str:
+    def register_function(self, function: str, buckets: set[str],
+                          arena_mb: float = 0.0) -> str:
         """Orchestrator provisions least-privilege credentials (§4.3.3)
         and establishes the tenant's shared-memory region up front (the
-        PCI-BAR mapping exists before the first invocation, §4.3.1).
-        Returns the opaque handle the guest may hold."""
-        self.arenas.get(function)
+        PCI-BAR mapping exists before the first invocation, §4.3.1), at
+        least `arena_mb` large. Returns the opaque handle the guest may
+        hold."""
+        self.arenas.get(function, arena_mb)
         return self.tokens.provision(function, buckets)
 
     def connection_setup(self, endpoint: str) -> float:

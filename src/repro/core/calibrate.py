@@ -75,6 +75,12 @@ SCALES = tuple(SERVING_SHAPES)
 PHASES = ("prefill", "decode", "encode")
 
 
+def serving_shapes(cfg) -> dict[str, tuple[int, int]]:
+    """The serving shapes a config runs at: `tiny` for SMOKE configs,
+    `full` for every config at published widths."""
+    return SERVING_SHAPES["tiny" if cfg.name.endswith("-smoke") else "full"]
+
+
 @dataclass(frozen=True)
 class MachineProfile:
     """The serving substrate a calibration targets, as pure data.
@@ -123,20 +129,24 @@ def shard_bytes(total: int, shards: int) -> list[int]:
 
 # ---------------------------------------------------------------- derivation
 
-def _derive_role(scale: str, role: str) -> dict:
-    """One (scale, role) calibration entry. Imports jax + the analytic
-    FLOPs machinery lazily: only regeneration pays for it — consumers
-    read the committed JSON."""
+#: one TPU v5e chip at its published peaks (Google Cloud documentation,
+#: "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM) with ``mfu=1.0``: the
+#: roofline lower bound, for suites that execute at chip size
+#: (`workloads.ml_suite_at`). Not part of the committed calibration.
+V5E = MachineProfile("tpu-v5e", peak_tflops=197.0, hbm_gbps=819.0,
+                     mfu=1.0, devices=1)
+
+
+def role_entry(role: str, cfg, machine: MachineProfile) -> dict:
+    """One calibration entry: `cfg` serving `role` on `machine`.
+    Imports jax + the analytic FLOPs machinery lazily: only
+    regeneration and chip-size suites pay for it — consumers of the
+    committed file read the JSON."""
     from repro.configs.base import InputShape
-    from repro.configs import registry
     from repro.models import serving
     from repro.models.flops import hbm_bytes_ideal, model_flops
 
-    arch = ML_ROLES[role]
-    cfg = registry.get(arch) if scale == "full" else registry.get_smoke(arch)
-    machine = MACHINES[scale]
-    shapes = SERVING_SHAPES[scale]
-
+    shapes = serving_shapes(cfg)
     phases = {}
     for phase in PHASES:
         B, S = shapes[phase]
@@ -162,6 +172,14 @@ def _derive_role(scale: str, role: str) -> dict:
         entry["weights_shard_bytes"] = shard_bytes(
             entry["params_bytes"], ROLE_SHARDS[role])
     return entry
+
+
+def _derive_role(scale: str, role: str) -> dict:
+    """One (scale, role) entry of the committed database."""
+    from repro.configs import registry
+    arch = ML_ROLES[role]
+    cfg = registry.get(arch) if scale == "full" else registry.get_smoke(arch)
+    return role_entry(role, cfg, MACHINES[scale])
 
 
 def derive_calibration() -> dict:

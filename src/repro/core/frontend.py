@@ -23,7 +23,7 @@ from __future__ import annotations
 import threading
 from concurrent.futures import TimeoutError as _FutureTimeout
 from dataclasses import dataclass, field
-from typing import Protocol, runtime_checkable
+from typing import Any, Protocol, runtime_checkable
 
 from repro.core import fabric as F
 from repro.core import metrics as M
@@ -82,6 +82,8 @@ class HandlerContext:
     invocation_id: str = ""
     function_name: str = ""
     cold_start: bool = False
+    #: the deployment's model configuration (`Workload.model`)
+    model: Any = None
     state: dict = field(default_factory=dict)
 
 
@@ -111,6 +113,7 @@ class NexusClient:
 
     def __init__(self, ctx: GuestContext, backend_ref, acct: M.CycleAccount,
                  *, max_retries: int = 3, ack_timeout_s: float = 30.0,
+                 connect_timeout_s: float = 30.0,
                  retry: RetrySpec | None = None, breaker=None):
         self._ctx = ctx
         # `backend_ref` is a callable returning the *current* backend —
@@ -130,6 +133,9 @@ class NexusClient:
         #: how long a blocking PUT waits for the durable ack before the
         #: invocation is failed (overridable per WorkerNode).
         self.ack_timeout_s = ack_timeout_s
+        #: how long the first GET waits for the ingress prefetch to land
+        #: (the node's ``connect_timeout_s``)
+        self.connect_timeout_s = connect_timeout_s
         self.pending_puts: list = []
 
     @property
@@ -226,7 +232,7 @@ class NexusClient:
             # (it fetched with the hint's own bits) — consume them so
             # later same-key GETs keep their per-op alignment
             self._admission(Bucket, Key)
-            slot = pf.wait()
+            slot = pf.wait(self.connect_timeout_s)
             self._charge_stub_call("aws", 0)     # pointer return: no bytes move
             return {"Body": slot.view(), "ContentLength": slot.used,
                     "_slot": slot}

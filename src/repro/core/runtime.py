@@ -178,7 +178,8 @@ class _GuestRun:
             self._seg_t0 = time.monotonic()
             hctx = HandlerContext(
                 storage=self, invocation_id=inv.inv_id,
-                function_name=inv.w.name, cold_start=inv.cold)
+                function_name=inv.w.name, cold_start=inv.cold,
+                model=inv.w.model)
             self.handler_result = inv.w.handler(inv.event, hctx)
             self._close_segments()
             if self._oi != len(self._ops):
@@ -550,7 +551,15 @@ class WorkerNode:
             fault_hooks=self.fault_hooks)
         if self.supervisor:
             self._creds[w.name] = self.backend.register_function(
-                w.name, {"in", "out"})
+                w.name, {"in", "out"}, arena_mb=self._arena_mb(w))
+
+    def _arena_mb(self, w: Workload) -> float:
+        """Arena an invocation of `w` needs: its GET slots stay leased
+        until the handler returns and its PUT slots until acked, so
+        every payload of one invocation can be resident at once."""
+        ops = (*w.profile.gets, *w.profile.puts)
+        return sum(max(int(op.size_bytes * self.byte_scale), 1024)
+                   for op in ops) / MB
 
     @staticmethod
     def _input_key(fn_name: str, i: int) -> str:
@@ -790,6 +799,7 @@ class WorkerNode:
                 ctx.gctx, lambda: self.supervisor.backend, self.acct,
                 max_retries=self.client_max_retries,
                 ack_timeout_s=self.writeback_ack_timeout_s,
+                connect_timeout_s=self.connect_timeout_s,
                 retry=self._retry_spec, breaker=self.guard.breaker)
 
     # --------------------------------------------------------- group actions

@@ -222,6 +222,9 @@ class Workload:
     handler: Callable[[dict, Any], Any]
     # deterministic input hint available at ingress (paper: 96% of fns)
     deterministic_input: bool = True
+    #: the model configuration an MLServe handler serves, handed to it
+    #: as ``ctx.model`` (``None``: the role's SMOKE config)
+    model: Any = None
 
     @property
     def input_mb(self) -> float:
@@ -446,6 +449,10 @@ def chaos_suite() -> dict[str, Workload]:
 #   round-tripped through ``ctx.storage``, durable outputs diffed
 #   byte-for-byte across every system variant.
 #
+# `ml_suite_at` builds the same scenarios, through the same handlers,
+# at any config a deployment names (the chip's granite-8b stage): its
+# sizes come from the shapes directly, so it imports jax.
+#
 # Handlers import the model stack lazily: the DES prices the profiles
 # without ever touching jax. Kept out of REGISTRY (like chaos_suite)
 # so the paper suite's denominators and parity goldens do not move.
@@ -456,7 +463,7 @@ def _ml_llm_cold_handler(event, ctx):
     from repro.models import serving
     bodies = [ctx.storage.get_object(Bucket=s["bucket"], Key=s["key"])["Body"]
               for s in event["inputs"]]
-    out = serving.llm_cold(bodies[:-1], bodies[-1])
+    out = serving.llm_cold(bodies[:-1], bodies[-1], ctx.model)
     dst = event["outputs"][0]
     ctx.storage.put_object(Bucket=dst["bucket"], Key=dst["key"], Body=out)
     return {"statusCode": 200, "bytes_out": len(out)}
@@ -468,7 +475,7 @@ def _ml_llm_prefill_handler(event, ctx):
     p, t = event["inputs"]
     params = ctx.storage.get_object(Bucket=p["bucket"], Key=p["key"])
     prompt = ctx.storage.get_object(Bucket=t["bucket"], Key=t["key"])
-    kv = serving.llm_prefill(params["Body"], prompt["Body"])
+    kv = serving.llm_prefill(params["Body"], prompt["Body"], ctx.model)
     dst = event["outputs"][0]
     ctx.storage.put_object(Bucket=dst["bucket"], Key=dst["key"], Body=kv)
     return {"statusCode": 200, "kv_bytes": len(kv)}
@@ -481,7 +488,7 @@ def _ml_llm_decode_handler(event, ctx):
     p, s = event["inputs"]
     params = ctx.storage.get_object(Bucket=p["bucket"], Key=p["key"])
     state = ctx.storage.get_object(Bucket=s["bucket"], Key=s["key"])
-    kv2, token = serving.llm_decode(params["Body"], state["Body"])
+    kv2, token = serving.llm_decode(params["Body"], state["Body"], ctx.model)
     dst = event["outputs"][0]
     ctx.storage.put_object(Bucket=dst["bucket"], Key=dst["key"], Body=kv2)
     return {"statusCode": 200, "token": token}
@@ -493,7 +500,7 @@ def _ml_emb_handler(event, ctx):
     p, t = event["inputs"]
     params = ctx.storage.get_object(Bucket=p["bucket"], Key=p["key"])
     tokens = ctx.storage.get_object(Bucket=t["bucket"], Key=t["key"])
-    out = serving.emb_encode(params["Body"], tokens["Body"])
+    out = serving.emb_encode(params["Body"], tokens["Body"], ctx.model)
     dst = event["outputs"][0]
     ctx.storage.put_object(Bucket=dst["bucket"], Key=dst["key"], Body=out)
     return {"statusCode": 200, "bytes_out": len(out)}
@@ -505,7 +512,7 @@ def _ml_moe_handler(event, ctx):
     from repro.models import serving
     bodies = [ctx.storage.get_object(Bucket=s["bucket"], Key=s["key"])["Body"]
               for s in event["inputs"]]
-    out = serving.moe_infer(bodies)
+    out = serving.moe_infer(bodies, ctx.model)
     dst = event["outputs"][0]
     ctx.storage.put_object(Bucket=dst["bucket"], Key=dst["key"], Body=out)
     return {"statusCode": 200, "bytes_out": len(out)}
@@ -527,64 +534,92 @@ def ml_suite(scale: str = "full") -> dict[str, Workload]:
     runtime executes them over real tensors; at ``full`` scale only
     the DES prices them.
     """
-    from repro.core.calibrate import load_calibration, model_entry
+    from repro.core.calibrate import ML_ROLES, load_calibration, model_entry
     if scale not in _ML_LIBS:
         raise ValueError(f"unknown ml_suite scale {scale!r}; "
                          f"known: {sorted(_ML_LIBS)}")
     cal = load_calibration()
-    llm = model_entry(scale, "llm", cal)
-    moe = model_entry(scale, "moe", cal)
-    emb = model_entry(scale, "emb", cal)
-    libs = _ML_LIBS[scale]
+    entries = {role: model_entry(scale, role, cal) for role in ML_ROLES}
+    return _ml_workloads(entries, _ML_LIBS[scale], {})
+
+
+def ml_suite_at(models: dict) -> dict[str, Workload]:
+    """The MLServe scenarios of the roles in `models` (role -> model
+    config), served at those configs through the same handlers.
+
+    GET/PUT sizes are exactly `serving.role_sizes(cfg)`, so this imports
+    jax; `ComputeSegment` budgets are the v5e roofline bound
+    (`calibrate.role_entry` on `calibrate.V5E`), which real device time
+    never undercuts, so they never pad a handler.
+    """
+    from repro.core.calibrate import V5E, role_entry
+    entries = {role: role_entry(role, cfg, V5E)
+               for role, cfg in models.items()}
+    return _ml_workloads(entries, _ML_LIBS["full"], models)
+
+
+def _ml_workloads(entries: dict, libs: dict,
+                  models: dict) -> dict[str, Workload]:
+    """Build the scenarios of every role in `entries` (calibration
+    entries keyed by role); `models` names each role's served config."""
 
     def mcyc(entry: dict, phase: str) -> float:
         return entry["phases"][phase]["mcycles"]
 
-    return {w.name: w for w in (
-        # cold start: weight-shard fan-in (first shard hint-prefetched
-        # at ingress -> overlaps the snapshot restore), prompt, prefill
-        # + one decode step, logits out.
-        Workload("LLM-COLD", IOProfile((
-            *[Get(s, key=f"shard{j}", shared=True)
-              for j, s in enumerate(llm["weights_shard_bytes"])],
-            Get(llm["prompt_bytes"], key="prompt"),
-            ComputeSegment(mcyc(llm, "prefill") + mcyc(llm, "decode")),
-            Put(llm["cold_out_bytes"]))), libs["llm"],
-            _ml_llm_cold_handler),
-        # prefill tier: params + prompt in, KV cache out (the durable
-        # handoff object a decode tier consumes).
-        Workload("LLM-PREFILL", IOProfile((
-            Get(llm["params_bytes"], key="params", shared=True),
-            Get(llm["prompt_bytes"], key="prompt"),
-            ComputeSegment(mcyc(llm, "prefill")),
-            Put(llm["kv_prefill_bytes"]))), libs["llm"],
-            _ml_llm_prefill_handler),
-        # decode tier: per-step KV GET + async KV PUT writeback — the
-        # paper's state-heavy-function case. The params and KV GETs are
-        # stable logical keys: after the first step on a node the whole
-        # chain is served from the SharedCache.
-        Workload("LLM-DECODE", IOProfile((
-            Get(llm["params_bytes"], key="params", shared=True),
-            Get(llm["kv_in_bytes"], key="kv"),
-            ComputeSegment(mcyc(llm, "decode")),
-            Put(llm["kv_out_bytes"], key="kv"))), libs["llm"],
-            _ml_llm_decode_handler),
+    out: list[Workload] = []
+    llm = entries.get("llm")
+    if llm is not None:
+        cfg = models.get("llm")
+        out += [
+            # cold start: weight-shard fan-in (first shard hint-prefetched
+            # at ingress -> overlaps the snapshot restore), prompt,
+            # prefill + one decode step, logits out.
+            Workload("LLM-COLD", IOProfile((
+                *[Get(s, key=f"shard{j}", shared=True)
+                  for j, s in enumerate(llm["weights_shard_bytes"])],
+                Get(llm["prompt_bytes"], key="prompt"),
+                ComputeSegment(mcyc(llm, "prefill") + mcyc(llm, "decode")),
+                Put(llm["cold_out_bytes"]))), libs["llm"],
+                _ml_llm_cold_handler, model=cfg),
+            # prefill tier: params + prompt in, KV cache out (the durable
+            # handoff object a decode tier consumes).
+            Workload("LLM-PREFILL", IOProfile((
+                Get(llm["params_bytes"], key="params", shared=True),
+                Get(llm["prompt_bytes"], key="prompt"),
+                ComputeSegment(mcyc(llm, "prefill")),
+                Put(llm["kv_prefill_bytes"]))), libs["llm"],
+                _ml_llm_prefill_handler, model=cfg),
+            # decode tier: per-step KV GET + async KV PUT writeback — the
+            # paper's state-heavy-function case. The params and KV GETs
+            # are stable logical keys: after the first step on a node the
+            # whole chain is served from the SharedCache.
+            Workload("LLM-DECODE", IOProfile((
+                Get(llm["params_bytes"], key="params", shared=True),
+                Get(llm["kv_in_bytes"], key="kv"),
+                ComputeSegment(mcyc(llm, "decode")),
+                Put(llm["kv_out_bytes"], key="kv"))), libs["llm"],
+                _ml_llm_decode_handler, model=cfg),
+        ]
+    emb = entries.get("emb")
+    if emb is not None:
         # batch encoder: params + token batch in, embedding block out.
-        Workload("EMB", IOProfile((
+        out.append(Workload("EMB", IOProfile((
             Get(emb["params_bytes"], key="params", shared=True),
             Get(emb["enc_tokens_bytes"], key="tokens"),
             ComputeSegment(mcyc(emb, "encode")),
             Put(emb["emb_bytes"]))), libs["emb"],
-            _ml_emb_handler),
+            _ml_emb_handler, model=models.get("emb")))
+    moe = entries.get("moe")
+    if moe is not None:
         # MoE: expert-shard fan-in (backbone + expert shards), one
         # routed batch, logits out.
-        Workload("MOE", IOProfile((
+        out.append(Workload("MOE", IOProfile((
             *[Get(s, key=f"shard{j}", shared=True)
               for j, s in enumerate(moe["weights_shard_bytes"])],
             ComputeSegment(mcyc(moe, "prefill")),
             Put(moe["moe_out_bytes"]))), libs["moe"],
-            _ml_moe_handler),
-    )}
+            _ml_moe_handler, model=models.get("moe")))
+    return {w.name: w for w in out}
 
 
 def compute_io_ratio(w: Workload, io_mcycles_per_mb: float = 12.0) -> float:

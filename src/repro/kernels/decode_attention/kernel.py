@@ -41,22 +41,22 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, slot_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
-    slots = slot_ref[0]                                  # (bk,) int32
-    pos = pos_ref[0]                                     # scalar int32
+    slots = slot_ref[0]                                  # (1, bk) int32
+    pos = pos_ref[pl.program_id(0)]                      # scalar (SMEM)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (1, bk)
     valid = (slots >= 0) & (slots <= pos)
     if window > 0:
         valid &= (pos - slots) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_ref[...]                                  # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * alpha
                     + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                           preferred_element_type=jnp.float32))
     m_ref[...] = m_new
@@ -64,7 +64,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, slot_ref, o_ref,
     @pl.when(ki == nk - 1)
     def _write():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)[None, None]
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)[None, None]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
@@ -72,7 +72,11 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, slot_ref, o_ref,
 def flash_decode(q, k_cache, v_cache, slot_pos, pos, *, window: int = 0,
                  block_k: int = 256, interpret: bool = True):
     """q: (B, H, 1, hd); k_cache, v_cache: (B, K, W, hd);
-    slot_pos: (B, W) int32; pos: (B,) int32. Returns (B, H, 1, hd)."""
+    slot_pos: (B, W) int32; pos: (B,) int32. Returns (B, H, 1, hd).
+
+    ``pos`` rides in SMEM by scalar prefetch, and ``slot_pos`` is viewed
+    as (B, 1, W) so each block's last two dims are (1, block_k): both
+    meet the TPU's block tiling rules at any batch size."""
     B, H, _, hd = q.shape
     K, W = k_cache.shape[1], k_cache.shape[2]
     G = H // K
@@ -88,29 +92,33 @@ def flash_decode(q, k_cache, v_cache, slot_pos, pos, *, window: int = 0,
                            constant_values=-1)
     nk = k_cache.shape[2] // block_k
 
-    grid = (B, H, nk)
     kernel = functools.partial(_decode_kernel, scale=scale,
                                block_k=block_k, window=window)
 
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),              # pos
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, ki: (b, ki)),   # slots
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, h, ki: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                               # pos
+            grid=(B, H, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, hd),
+                             lambda b, h, ki, pos: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b, h, ki, pos, G=G: (b, h // G, ki, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b, h, ki, pos, G=G: (b, h // G, ki, 0)),
+                pl.BlockSpec((1, 1, block_k),
+                             lambda b, h, ki, pos: (b, 0, ki)),  # slots
+            ],
+            out_specs=pl.BlockSpec((1, 1, 1, hd),
+                                   lambda b, h, ki, pos: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(pos, q, k_cache, v_cache, slot_pos)
+    )(pos.astype(jnp.int32), q, k_cache, v_cache,
+      slot_pos.astype(jnp.int32)[:, None, :])
     return out

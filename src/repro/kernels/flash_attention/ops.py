@@ -1,34 +1,26 @@
-"""jit'd public wrapper: (B, S, H, hd) layout in/out, kernel or oracle.
+"""Public wrapper: (B, S, H, hd) layout in/out, kernel or oracle.
 
-`use_kernel='auto'` picks the Pallas kernel on TPU backends and the
-blocked-jnp path elsewhere; tests force `use_kernel=True` with
-interpret=True to validate the kernel body on CPU.
+The caller chooses both the path (`use_kernel`) and, for the kernel,
+whether it runs compiled for the TPU or in the Pallas interpreter
+(`interpret`); nothing here looks at the backend it finds.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def mha(q, k, v, *, causal: bool = True, window: int = 0,
-        use_kernel: bool | str = "auto", block_q: int = 128,
+def mha(q, k, v, *, use_kernel: bool, interpret: bool = False,
+        causal: bool = True, window: int = 0, block_q: int = 128,
         block_k: int = 128):
     """q: (B, S, H, hd); k, v: (B, S, K, hd) -> (B, S, H, hd)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if use_kernel == "auto":
-        use_kernel = _on_tpu()
     if use_kernel:
         ot = flash_attention(qt, kt, vt, causal=causal, window=window,
                              block_q=block_q, block_k=block_k,
-                             interpret=not _on_tpu())
+                             interpret=interpret)
     else:
         ot = attention_ref(qt, kt, vt, causal=causal, window=window)
     return ot.transpose(0, 2, 1, 3)

@@ -37,25 +37,23 @@ def _ssm_kernel(dt_ref, xr_ref, b_ref, c_ref, a_ref, h0_ref, y_ref,
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)       # (bd, N)
 
-    dt = dt_ref[0].astype(jnp.float32)                   # (chunk, bd)
-    xr = xr_ref[0].astype(jnp.float32)                   # (chunk, bd)
-    bm = b_ref[0].astype(jnp.float32)                    # (chunk, N)
-    cm = c_ref[0].astype(jnp.float32)                    # (chunk, N)
     a = a_ref[...].astype(jnp.float32)                   # (bd, N)
+    bd = a.shape[0]
 
-    def step(t, carry):
-        h, ys = carry
-        da = jnp.exp(dt[t][:, None] * a)                 # (bd, N)
-        dbx = (dt[t] * xr[t])[:, None] * bm[t][None, :]  # (bd, N)
-        h = h * da + dbx
-        y_t = jnp.sum(h * cm[t][None, :], axis=-1)       # (bd,)
-        return h, jax.lax.dynamic_update_index_in_dim(ys, y_t, t, 0)
+    def step(t, h):
+        # one time step's rows, read through ref slices: Mosaic lowers a
+        # dynamic sublane offset on a ref, not on a loaded value
+        row = pl.ds(t, 1)
+        dt_t = dt_ref[0, row, :].astype(jnp.float32).reshape(bd, 1)
+        xr_t = xr_ref[0, row, :].astype(jnp.float32).reshape(bd, 1)
+        b_t = b_ref[0, row, :].astype(jnp.float32)        # (1, N)
+        c_t = c_ref[0, row, :].astype(jnp.float32)        # (1, N)
+        h = h * jnp.exp(dt_t * a) + (dt_t * xr_t) * b_t   # (bd, N)
+        y_t = jnp.sum(h * c_t, axis=-1, keepdims=True)    # (bd, 1)
+        y_ref[0, row, :] = y_t.reshape(1, bd).astype(y_ref.dtype)
+        return h
 
-    h0 = h_ref[...]
-    ys0 = jnp.zeros((chunk, dt.shape[1]), jnp.float32)
-    h, ys = jax.lax.fori_loop(0, chunk, step, (h0, ys0))
-    h_ref[...] = h
-    y_ref[...] = ys[None]
+    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
 
     @pl.when(ci == nc - 1)
     def _write_state():

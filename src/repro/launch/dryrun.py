@@ -170,8 +170,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    from repro.models.jax_compat import cost_analysis as _cost_analysis
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     analysis = None

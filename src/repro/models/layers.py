@@ -13,7 +13,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models import jax_compat as jc
+from jax.sharding import AxisType, PartitionSpec
 
 NEG_INF = -1e30
 
@@ -30,12 +30,13 @@ def shard_hint(x, *dims):
     dims: per-axis logical roles — 'batch' (pod+data), 'model', or None.
     No-op outside a mesh context (unit tests, single-device runs).
     """
-    mesh = jc.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     # constraints may only name Auto axes (inside shard_map the mapped
     # axes are Manual and already pinned)
-    auto = jc.auto_axis_names(mesh)
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
     fsdp = tuple(a for a in mesh.axis_names
                  if a in ("pod", "data") and a in auto)
     spec = []
@@ -54,7 +55,7 @@ def shard_hint(x, *dims):
             spec.append("model")
         else:
             spec.append(None)
-    return jc.with_sharding_constraint(x, spec)
+    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
 
 
 

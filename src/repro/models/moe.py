@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models import jax_compat as jc
 from repro.models.layers import dense_init, shard_hint, split_rngs
 
 
@@ -83,7 +82,7 @@ def moe_sorted(p, cfg, x):
     # pin capacity to the data axes so the FFN never gathers the full
     # (E, cap, D) buffer. True-EP archs (qwen3: E=128) keep GSPMD's own
     # expert-sharded layout — hinting them regressed 10x (SPerf log).
-    mesh = jc.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     model_n = dict(mesh.shape).get("model", 1) if mesh.axis_names else 1
     if model_n > 1 and E % model_n != 0:
         buf = shard_hint(buf, None, "batch", None)
@@ -129,7 +128,7 @@ def moe_local(p, cfg, x):
     T/shards tokens into a local capacity buffer. No global sort, no
     dispatch collectives; the load-balance statistics are pmean'd.
     """
-    mesh = jc.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     fsdp = tuple(a for a in (mesh.axis_names or ())
                  if a in ("pod", "data"))
     n = 1

@@ -46,10 +46,8 @@ def tree_nbytes(shapes) -> int:
 def dumps(tree) -> bytes:
     """Encode a tree of arrays to its canonical flat byte string."""
     import numpy as np
-    out = bytearray()
-    for leaf in _leaves(tree):
-        out += np.ascontiguousarray(np.asarray(leaf)).tobytes()
-    return bytes(out)
+    return b"".join(np.ascontiguousarray(np.asarray(leaf)).tobytes()
+                    for leaf in _leaves(tree))
 
 
 def loads(shapes, data):

@@ -7,7 +7,8 @@ never drift:
   arithmetic) when regenerating ``calibration.json`` — the byte sizes
   the `IOProfile`s declare;
 * the MLServe handlers in `core.workloads` call the ``llm_*`` /
-  ``emb_*`` / ``moe_*`` cores at **tiny** scale: real SMOKE-config
+  ``emb_*`` / ``moe_*`` cores at the config their deployment names
+  (`Workload.model`; the role's SMOKE config by default): real
   forwards over real tensors decoded from the bytes ``ctx.storage``
   handed them, re-encoded with the same deterministic codec
   (`models.serialize`) before the PUT.
@@ -25,7 +26,7 @@ from __future__ import annotations
 import functools
 
 from repro.core.calibrate import (LLM_WEIGHT_SHARDS, ML_ROLES, MOE_SHARDS,
-                                  SERVING_SHAPES, shard_bytes)
+                                  serving_shapes, shard_bytes)
 from repro.models import serialize
 
 #: scenario name -> (role, list of payload kinds in IOProfile GET order)
@@ -57,8 +58,7 @@ def _structs_for(cfg):
     from repro.models import get_model
 
     model = get_model(cfg)
-    shapes = SERVING_SHAPES["tiny" if cfg.name.endswith("-smoke")
-                            else "full"]
+    shapes = serving_shapes(cfg)
     (Bp, Sp), (Bd, Sd), (Be, Se) = (shapes["prefill"], shapes["decode"],
                                     shapes["encode"])
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
@@ -115,56 +115,83 @@ def role_sizes(cfg, devices: int = 1) -> dict:
     }
 
 
-# ------------------------------------------------------- tiny-scale bundle
+# ------------------------------------------------------------ model bundle
+
+def role_config(role: str, cfg=None):
+    """The config a role serves: `cfg` when a deployment names one
+    (`Workload.model`), else the role's SMOKE config."""
+    if cfg is not None:
+        return cfg
+    from repro.configs import registry
+    return registry.get_smoke(ML_ROLES[role])
+
 
 @functools.lru_cache(maxsize=None)
-def _bundle(role: str):
-    """(cfg, model, params, jitted prefill/decode) for one tiny role.
+def bundle(cfg):
+    """cfg, model, shape structs and jitted prefill/decode of a config.
 
-    Params come from a fixed PRNGKey — every process derives the same
-    tensors; jits are cached here so the transparency sweep compiles
-    each tiny model once, not once per variant.
+    Holds no parameters: at chip size a resident seed copy would sit on
+    the device beside every invocation's decoded copy. Jits are cached
+    here so the transparency sweep compiles each model once, not once
+    per variant.
     """
     import jax
-    from repro.configs import registry
     from repro.models import get_model
 
-    cfg = registry.get_smoke(ML_ROLES[role])
     model = get_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
     return {
-        "cfg": cfg, "model": model, "params": params,
+        "cfg": cfg, "model": model,
         "structs": _structs_for(cfg),
         "prefill": jax.jit(model.prefill),
         "decode": jax.jit(model.decode_step),
     }
 
 
-def _prompt_tokens(role: str, which: str = "prompt"):
+def _prompt_tokens(b: dict, which: str = "prompt"):
     """Deterministic prompt: a fixed arithmetic progression mod vocab."""
     import jax.numpy as jnp
     import numpy as np
-    b = _bundle(role)
     shape = b["structs"][which].shape
     n = int(np.prod(shape))
     toks = (np.arange(n, dtype=np.int64) * 7 + 3) % b["cfg"].vocab_size
     return jnp.asarray(toks.astype(np.int32).reshape(shape))
 
 
-def _next_token(logits):
+def next_token(logits):
+    """Greedy next token of each row: (B, S, V) logits -> (B, 1) int32."""
     import jax.numpy as jnp
     return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
 
 
 # -------------------------------------------------- seeding (test harness)
 
-def seed_payloads(scenario: str) -> list[bytes]:
-    """The tiny-scale input objects for one scenario, in GET order —
-    what a deployment stages in remote storage before invoking. Byte
-    sizes match the tiny `IOProfile` (and `calibration.json`) exactly."""
+def _seed_blobs(b: dict, with_kv: bool) -> tuple[bytes, bytes | None]:
+    """(params, decode-ready KV) blobs. Params come from a fixed PRNGKey,
+    so every process derives the same random weights. The device arrays
+    die with this frame: only invocations' copies stay on the chip."""
+    import jax
+    params = b["model"].init_params(jax.random.PRNGKey(0))
+    kv = None
+    if with_kv:
+        # a real decode-ready state: prefill a DECODE-shaped fixed
+        # prompt (the `decode_cache` struct the handler and
+        # calibration declare is derived from exactly this shape —
+        # the prompt shape need not coincide), then serialize
+        # (cache, next-token) — the decode GET payload
+        logits, cache = b["prefill"](
+            params, {"tokens": _prompt_tokens(b, "decode_tokens")})
+        kv = serialize.dumps((cache, next_token(logits)))
+    return serialize.dumps(params), kv
+
+
+def seed_payloads(scenario: str, cfg=None) -> list[bytes]:
+    """The input objects for one scenario, in GET order — what a
+    deployment stages in remote storage before invoking. Byte sizes
+    match the scenario's `IOProfile` exactly (at SMOKE configs, the
+    tiny profiles in `calibration.json`)."""
     role, kinds = SCENARIO_INPUTS[scenario]
-    b = _bundle(role)
-    params_blob = serialize.dumps(b["params"])
+    b = bundle(role_config(role, cfg))
+    params_blob, kv_blob = _seed_blobs(b, "kv" in kinds)
 
     out: list[bytes] = []
     shards: list[bytes] = []
@@ -181,76 +208,71 @@ def seed_payloads(scenario: str) -> list[bytes]:
         elif kind == "params":
             out.append(params_blob)
         elif kind == "prompt":
-            out.append(serialize.dumps(_prompt_tokens(role, "prompt")))
+            out.append(serialize.dumps(_prompt_tokens(b, "prompt")))
         elif kind == "enc_tokens":
-            out.append(serialize.dumps(_prompt_tokens(role, "enc_tokens")))
+            out.append(serialize.dumps(_prompt_tokens(b, "enc_tokens")))
         elif kind == "kv":
-            # a real decode-ready state: prefill a DECODE-shaped fixed
-            # prompt (the `decode_cache` struct the handler and
-            # calibration declare is derived from exactly this shape —
-            # the prompt shape need not coincide), then serialize
-            # (cache, next-token) — the decode GET payload
-            logits, cache = b["prefill"](
-                b["params"], {"tokens": _prompt_tokens(role,
-                                                       "decode_tokens")})
-            out.append(serialize.dumps((cache, _next_token(logits))))
+            out.append(kv_blob)
         else:                                    # pragma: no cover
             raise ValueError(kind)
     return out
 
 
 # ------------------------------------------------------------ handler cores
+#
+# `cfg` is the deployment's `Workload.model` (None: the role's SMOKE
+# config). Each core decodes its own copy of the weights from the bytes
+# `ctx.storage` handed it; that copy is the only one on the device.
 
-def _load_params(role: str, blob):
-    b = _bundle(role)
+def _load_params(b: dict, blob):
     return serialize.loads(b["structs"]["params"], blob)
 
 
-def llm_cold(shard_bodies, prompt_body) -> bytes:
+def llm_cold(shard_bodies, prompt_body, cfg=None) -> bytes:
     """Assemble weights from shards, prefill the prompt, take one decode
     step; the durable output is the step's logits."""
-    b = _bundle("llm")
-    params = _load_params("llm", b"".join(bytes(s) for s in shard_bodies))
+    b = bundle(role_config("llm", cfg))
+    params = _load_params(b, b"".join(shard_bodies))
     tokens = serialize.loads(b["structs"]["prompt"], prompt_body)
     logits, cache = b["prefill"](params, {"tokens": tokens})
-    logits2, _ = b["decode"](params, cache, _next_token(logits))
+    logits2, _ = b["decode"](params, cache, next_token(logits))
     return serialize.dumps(logits2)
 
 
-def llm_prefill(params_body, prompt_body) -> bytes:
+def llm_prefill(params_body, prompt_body, cfg=None) -> bytes:
     """Prefill: the durable output is the serialized KV cache the decode
     tier would consume."""
-    b = _bundle("llm")
-    params = _load_params("llm", params_body)
+    b = bundle(role_config("llm", cfg))
+    params = _load_params(b, params_body)
     tokens = serialize.loads(b["structs"]["prompt"], prompt_body)
     _, cache = b["prefill"](params, {"tokens": tokens})
     return serialize.dumps(cache)
 
 
-def llm_decode(params_body, kv_body) -> tuple[bytes, int]:
+def llm_decode(params_body, kv_body, cfg=None) -> tuple[bytes, int]:
     """One decode step: deserialize (cache, token), advance the model,
     return (serialized updated cache, next token id)."""
-    b = _bundle("llm")
-    params = _load_params("llm", params_body)
+    b = bundle(role_config("llm", cfg))
+    params = _load_params(b, params_body)
     cache, token = serialize.loads(
         (b["structs"]["decode_cache"], b["structs"]["step_token"]), kv_body)
     logits, cache2 = b["decode"](params, cache, token)
-    return serialize.dumps(cache2), int(_next_token(logits)[0, 0])
+    return serialize.dumps(cache2), int(next_token(logits)[0, 0])
 
 
-def emb_encode(params_body, tokens_body) -> bytes:
+def emb_encode(params_body, tokens_body, cfg=None) -> bytes:
     """Batch encode: final-position logits as the embedding vectors."""
-    b = _bundle("emb")
-    params = _load_params("emb", params_body)
+    b = bundle(role_config("emb", cfg))
+    params = _load_params(b, params_body)
     tokens = serialize.loads(b["structs"]["enc_tokens"], tokens_body)
     logits, _ = b["prefill"](params, {"tokens": tokens})
     return serialize.dumps(logits)
 
 
-def moe_infer(shard_bodies) -> bytes:
+def moe_infer(shard_bodies, cfg=None) -> bytes:
     """Expert-shard fan-in: reassemble the MoE params from the fetched
     shards, run the fixed prompt through the router + top-k experts."""
-    b = _bundle("moe")
-    params = _load_params("moe", b"".join(bytes(s) for s in shard_bodies))
-    logits, _ = b["prefill"](params, {"tokens": _prompt_tokens("moe")})
+    b = bundle(role_config("moe", cfg))
+    params = _load_params(b, b"".join(shard_bodies))
+    logits, _ = b["prefill"](params, {"tokens": _prompt_tokens(b)})
     return serialize.dumps(logits)

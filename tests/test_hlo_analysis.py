@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 
 from benchmarks.hlo_analysis import analyze
-from repro.models.jax_compat import cost_analysis
 
 D = 256
 ITERS = 10
@@ -37,9 +36,9 @@ class TestLoopCorrection:
     def test_xla_cost_analysis_undercounts_scans(self, lowered):
         """The motivating defect: XLA counts a while body once."""
         scan, unroll = lowered
-        assert cost_analysis(scan)["flops"] == pytest.approx(
+        assert scan.cost_analysis()["flops"] == pytest.approx(
             FLOPS_ONE_MATMUL, rel=0.01)
-        assert cost_analysis(unroll)["flops"] == pytest.approx(
+        assert unroll.cost_analysis()["flops"] == pytest.approx(
             ITERS * FLOPS_ONE_MATMUL, rel=0.01)
 
     def test_analyzer_is_loop_exact(self, lowered):

@@ -56,6 +56,27 @@ class TestCalibration:
             declared = [g.size_bytes for g in w.profile.gets]
             assert declared == [len(p) for p in payloads], name
 
+    def test_suite_at_a_config_declares_its_exact_sizes(self):
+        """`ml_suite_at` serves a chosen config through the same handler
+        objects; at the SMOKE configs its GET/PUT sizes are exactly the
+        committed tiny calibration's, and each workload names the
+        config its handler is handed."""
+        from repro.configs import registry
+        from repro.core.calibrate import ML_ROLES
+        from repro.core.workloads import ml_suite_at
+        models = {r: registry.get_smoke(a) for r, a in ML_ROLES.items()}
+        at, tiny = ml_suite_at(models), ml_suite("tiny")
+        assert list(at) == list(tiny)
+        for name, w in at.items():
+            t = tiny[name]
+            assert w.handler is t.handler and t.model is None
+            assert w.model is models[
+                "emb" if name == "EMB" else "moe" if name == "MOE" else "llm"]
+            assert [g.size_bytes for g in w.profile.gets] == \
+                [g.size_bytes for g in t.profile.gets]
+            assert [p.size_bytes for p in w.profile.puts] == \
+                [p.size_bytes for p in t.profile.puts]
+
     def test_full_scale_is_serving_sized(self):
         """The full-scale suite carries the paper's motivation: weight
         shards are hundreds of MB, decode KV state is tens-to-hundreds

@@ -297,6 +297,21 @@ class TestCrashRecovery:
             sup.stop()
 
 
+def test_first_get_waits_for_prefetch_as_long_as_the_node_says():
+    """The guest's wait on the ingress prefetch is the node's connect
+    deadline, not a fixed one: a multi-GB weights fetch outlasts 30 s."""
+    from repro.core.backend import PrefetchHandle
+    handle = PrefetchHandle(InputHint("in", "weights", 1 << 30))
+    ctx = GuestContext(tenant="fn", cred_handle="h", invocation_id="i",
+                       prefetch=handle)
+    client = NexusClient(ctx, lambda: None, M.CycleAccount(),
+                         connect_timeout_s=0.2)
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError):
+        client.get_object(Bucket="in", Key="weights")
+    assert time.monotonic() - t0 < 5.0
+
+
 # ------------------------------------------------------- end-to-end runtime
 
 class TestWorkerNode:
@@ -310,6 +325,25 @@ class TestWorkerNode:
             assert res.output_etag is not None
             # at-least-once: the output object really is in storage
             assert node.store.head("out", f"{res.invocation_id}-out").size > 0
+        finally:
+            node.shutdown()
+
+    def test_arena_sized_from_the_deployed_profile(self):
+        """One invocation holds its 48 MB input slot while it writes a
+        24 MB output: more than the registry's 64 MB default. Deploy
+        sizes the tenant arena from the profile, so it still runs."""
+        from repro.core.workloads import (MB, IOProfile, Workload,
+                                          _single_io_handler)
+        w = Workload("BIG", IOProfile.single(48.0, 24.0, 1.0), 10.0,
+                     _single_io_handler(lambda v: bytes(v[:24 * MB])))
+        node = WorkerNode("nexus", byte_scale=1.0)
+        try:
+            node.deploy(w)
+            assert node._arenas.get("BIG").capacity >= 72 * MB
+            node.seed_input("BIG")
+            res = node.invoke("BIG").result(timeout=60)
+            assert node.store.head("out", f"{res.invocation_id}-out").size \
+                == 24 * MB
         finally:
             node.shutdown()
 
