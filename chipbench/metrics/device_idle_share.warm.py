@@ -1,0 +1,7 @@
+"""The share of the traced window in which no operation ran on the
+device, in percent."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.idle_share(run)

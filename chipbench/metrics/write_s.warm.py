@@ -1,0 +1,6 @@
+"""Mean seconds per warm invocation in the ``write[k]`` groups."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.mean_groups(run, "write[", cold=False)
