@@ -37,6 +37,8 @@ import sys
 import time
 import traceback
 
+from chipbench.harness import CompileLog
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 SCENARIOS = ("LLM-COLD", "LLM-PREFILL", "LLM-DECODE", "EMB")
@@ -51,42 +53,6 @@ CONNECT_TIMEOUT_S = 150.0
 #: host-to-device copy of the weights and the compile inside the handler
 #: (106 s measured for LLM-DECODE on a TPU v5e host, compile cache warm)
 PLAN_STALL_TIMEOUT_S = 240.0
-
-_COMPILE = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT = "/jax/compilation_cache/cache_hits"
-_CACHE_WRITE = "/jax/compilation_cache/cache_misses"
-
-
-class CompileLog:
-    """Counts, while open, the executables JAX builds (compiled or
-    loaded from the persistent cache) and the persistent cache's hits
-    and writes."""
-
-    def __init__(self):
-        self.built = self.hits = self.writes = 0
-        self.build_s = 0.0
-
-    def _event(self, event, **_kw):
-        if event == _CACHE_HIT:
-            self.hits += 1
-        elif event == _CACHE_WRITE:
-            self.writes += 1
-
-    def _duration(self, event, secs, **_kw):
-        if event == _COMPILE:
-            self.built += 1
-            self.build_s += secs
-
-    def __enter__(self):
-        import jax
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-        jax.monitoring.unregister_event_listener(self._event)
-        jax.monitoring.unregister_event_duration_listener(self._duration)
 
 
 def _sha(data) -> str:

@@ -18,6 +18,8 @@ import threading
 import time
 from dataclasses import dataclass
 
+from repro.core import metrics as M
+
 MB = 1024 * 1024
 
 
@@ -106,6 +108,10 @@ class TenantArena:
         the crash-only escalation point."""
         if size <= 0:
             raise ArenaError("size must be positive")
+        with M.span("nexus.arena.alloc_wait", bytes=size):
+            return self._alloc_wait(size, timeout_s)
+
+    def _alloc_wait(self, size: int, timeout_s: float) -> Slot:
         with self._reclaimed:
             slot = self._try_alloc(size)
             if slot is not None:

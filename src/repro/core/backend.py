@@ -17,7 +17,6 @@ One backend process multiplexes I/O for every co-resident instance:
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 
@@ -153,7 +152,7 @@ class NexusBackend:
                 return 0.0
             self._conn_established.add(endpoint)
         t = self.remote.transport.setup_latency_s
-        time.sleep(t)
+        M.wait("connect", t)
         self.acct.charge(M.HOST_USER, 0.3 if self.remote.transport.kernel_bypass
                          else 0.15)
         return t
@@ -172,7 +171,7 @@ class NexusBackend:
         data availability, so they are slept (they shape fetch latency)
         as well as accounted (host-user, via remoted_op_cost)."""
         nominal = int(nbytes * self.remote.cost_scale)
-        time.sleep(F.fabric_op_mcycles("aws", "go", nominal) / 2100.0)
+        M.wait("sdk", F.fabric_op_mcycles("aws", "go", nominal) / 2100.0)
 
     def _authorized_get(self, tenant: str, cred: str, bucket: str,
                         key: str, *, hinted: bool = True,
@@ -192,7 +191,7 @@ class NexusBackend:
                              hinted=hinted)
             if data is not None:
                 self.stats["cache_hits"] += 1
-                time.sleep(cache.spec.hit_duration_s(
+                M.wait("hit", cache.spec.hit_duration_s(
                     int(len(data) * self.remote.cost_scale)))
                 return data
         # bytes and etag come from ONE atomic store snapshot: a PUT
@@ -223,27 +222,29 @@ class NexusBackend:
 
         def _run():
             try:
-                self._check_alive()
-                if pre_connect is not None:
-                    self.connection_setup(pre_connect)
-                data = self._authorized_get(tenant, cred, hint.bucket,
-                                            hint.key, hinted=True,
-                                            use_cache=hint.cacheable)
-                size = len(data)
-                # arena pressure is transient: stall for reclaim rather
-                # than failing the fetch outright (§4.3.1)
-                slot = self.arenas.get(tenant).alloc_wait(
-                    max(size, 1), timeout_s=self.alloc_timeout_s)
-                slot.write(data)
-                # RDMA: NIC DMAs straight into the registered arena —
-                # charged inside the transport model (zero host-kernel).
-                handle.slot = slot
+                with M.span("nexus.backend.prefetch"):
+                    self._check_alive()
+                    if pre_connect is not None:
+                        self.connection_setup(pre_connect)
+                    data = self._authorized_get(tenant, cred, hint.bucket,
+                                                hint.key, hinted=True,
+                                                use_cache=hint.cacheable)
+                    size = len(data)
+                    # arena pressure is transient: stall for reclaim
+                    # rather than failing the fetch outright (§4.3.1)
+                    slot = self.arenas.get(tenant).alloc_wait(
+                        max(size, 1), timeout_s=self.alloc_timeout_s)
+                    with M.span("nexus.arena.write", bytes=size):
+                        slot.write(data)
+                    # RDMA: NIC DMAs straight into the registered arena —
+                    # charged inside the transport model (zero host-kernel).
+                    handle.slot = slot
             except BaseException as e:      # noqa: BLE001 — propagated
                 handle.error = e
             finally:
                 handle.ready.set()
 
-        self._pool.submit(_run)
+        self._pool.submit(M.carry(_run))
         return handle
 
     def fetch_sync(self, tenant: str, cred: str, bucket: str, key: str,
@@ -251,11 +252,13 @@ class NexusBackend:
         """Synchronous remoted GET (Nexus-TCP path / no hints)."""
         self._check_alive()
         self.stats["sync_gets"] += 1
-        data = self._authorized_get(tenant, cred, bucket, key,
-                                    hinted=hinted, use_cache=cacheable)
-        slot = self.arenas.get(tenant).alloc_wait(
-            max(len(data), 1), timeout_s=self.alloc_timeout_s)
-        slot.write(data)
+        with M.span("nexus.backend.fetch_sync"):
+            data = self._authorized_get(tenant, cred, bucket, key,
+                                        hinted=hinted, use_cache=cacheable)
+            slot = self.arenas.get(tenant).alloc_wait(
+                max(len(data), 1), timeout_s=self.alloc_timeout_s)
+            with M.span("nexus.arena.write", bytes=len(data)):
+                slot.write(data)
         return slot
 
     def fetch_stream(self, tenant: str, cred: str, bucket: str, key: str,
@@ -280,7 +283,7 @@ class NexusBackend:
             else:
                 buf.close()
 
-        self._pool.submit(_run)
+        self._pool.submit(M.carry(_run))
 
     # -------------------------------------------------------------- writes
 
@@ -300,50 +303,58 @@ class NexusBackend:
 
         def _run():
             try:
-                self._check_alive()
-                with self._lock:
-                    done = self._completed_puts.get(dedup_key)
-                if done is not None:
-                    self.stats["dedup_hits"] += 1
-                    slot.release()       # the retry's copy is never sent
-                    ticket.future.set_result(done)
-                    return
-                self.tokens.authorize(cred, out.bucket, "put")
-                self.connection_setup(out.bucket)
-                view = slot.view()
-                self._run_sdk(len(view))
-                self.limiter.bucket("s3").throttle(len(view))
-                meta = self.remote.put(out.bucket, out.key, view)
-                with self._lock:
-                    self._completed_puts[dedup_key] = meta.etag
-                cache = self.cache
-                if cache is not None:
-                    # write-through strictly AFTER the remote PUT
-                    # committed durably (never caches an unacked
-                    # write); bytes copied before the slot goes back
-                    cache.put(tenant, out.bucket, out.key, bytes(view),
-                              int(len(view) * self.remote.cost_scale),
-                              meta.etag)
-                slot.release()
-                # FaultPlane ack-drop tap: the write IS durable and the
-                # idempotency record exists — only the ack is lost. The
-                # frontend's timed-out wait redrives and dedup resolves.
-                hooks = self.fault_hooks
-                if (hooks is not None and hooks.ack_drop is not None
-                        and hooks.ack_drop(dedup_key)):
-                    self.stats["acks_dropped"] += 1
-                    return
-                ticket.future.set_result(meta.etag)
+                with M.span("nexus.backend.put"):
+                    etag = self._put(slot, tenant, cred, out, dedup_key)
             except BaseException as e:      # noqa: BLE001
-                # the attempt failed BEFORE the release above: free the
-                # slot now (idempotent) — arenas outlive backend crashes
-                # by design, so a leak here would be permanent, and the
+                # the attempt failed BEFORE the slot went back: free it
+                # now (idempotent) — arenas outlive backend crashes by
+                # design, so a leak here would be permanent, and the
                 # frontend's recovery re-submits with a fresh slot.
                 slot.release()
                 ticket.future.set_exception(e)
+                return
+            if etag is not None:
+                ticket.future.set_result(etag)
 
-        self._pool.submit(_run)
+        self._pool.submit(M.carry(_run))
         return ticket
+
+    def _put(self, slot: Slot, tenant: str, cred: str, out: OutputHint,
+             dedup_key: str) -> int | None:
+        """One durable write; returns the etag to ack, or None when the
+        ack is dropped."""
+        self._check_alive()
+        with self._lock:
+            done = self._completed_puts.get(dedup_key)
+        if done is not None:
+            self.stats["dedup_hits"] += 1
+            slot.release()       # the retry's copy is never sent
+            return done
+        self.tokens.authorize(cred, out.bucket, "put")
+        self.connection_setup(out.bucket)
+        view = slot.view()
+        self._run_sdk(len(view))
+        self.limiter.bucket("s3").throttle(len(view))
+        meta = self.remote.put(out.bucket, out.key, view)
+        with self._lock:
+            self._completed_puts[dedup_key] = meta.etag
+        cache = self.cache
+        if cache is not None:
+            # write-through strictly AFTER the remote PUT committed
+            # durably (never caches an unacked write); bytes copied
+            # before the slot goes back
+            cache.put(tenant, out.bucket, out.key, bytes(view),
+                      int(len(view) * self.remote.cost_scale), meta.etag)
+        slot.release()
+        # FaultPlane ack-drop tap: the write IS durable and the
+        # idempotency record exists — only the ack is lost. The
+        # frontend's timed-out wait redrives and dedup resolves.
+        hooks = self.fault_hooks
+        if (hooks is not None and hooks.ack_drop is not None
+                and hooks.ack_drop(dedup_key)):
+            self.stats["acks_dropped"] += 1
+            return None
+        return meta.etag
 
     def redrive_put(self, tenant: str, cred: str, out: OutputHint,
                     invocation_id: str) -> PutTicket:

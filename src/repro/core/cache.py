@@ -62,6 +62,7 @@ import random
 import threading
 from dataclasses import dataclass
 
+from repro.core import metrics as M
 from repro.core.arena import ArenaError, ArenaRegistry, Slot
 
 MB = 1024 * 1024
@@ -339,6 +340,12 @@ class SharedCache:
         """Cache-consulting GET. Returns immutable payload bytes on a
         validated hit, ``None`` on any miss (the caller then takes the
         remote path and offers the result back via `fill`)."""
+        with M.span("nexus.cache.get") as s:
+            data = self._get(bucket, key, store)
+            s.attrs["bytes"] = len(data) if data is not None else 0
+        return data
+
+    def _get(self, bucket: str, key: str, store) -> bytes | None:
         lk = self._lk(bucket, key)
 
         def _valid(lk_: str, _ck: str) -> bool:
@@ -370,28 +377,30 @@ class SharedCache:
         entry's bytes (possibly older) would create a stale hit, and
         parking a payload under an unreferenced content key would leak
         its arena slot."""
-        lk = self._lk(bucket, key)
-        ck = self._ck(tenant, data)
-        with self._lock:
-            if not self.state.fill(lk, ck, nominal_size, hinted=hinted):
-                return False
-            self._etag[lk] = etag
-            if ck not in self._payload:
-                self._payload[ck] = self._park(tenant, data)
-            return True
+        with M.span("nexus.cache.fill", bytes=len(data)):
+            lk = self._lk(bucket, key)
+            ck = self._ck(tenant, data)
+            with self._lock:
+                if not self.state.fill(lk, ck, nominal_size, hinted=hinted):
+                    return False
+                self._etag[lk] = etag
+                if ck not in self._payload:
+                    self._payload[ck] = self._park(tenant, data)
+                return True
 
     def put(self, tenant: str, bucket: str, key: str, data: bytes,
             nominal_size: int, etag: int) -> bool:
         """Write-through after the remote PUT committed durably."""
-        lk = self._lk(bucket, key)
-        ck = self._ck(tenant, data)
-        with self._lock:
-            if not self.state.write(lk, ck, nominal_size):
-                return False
-            self._etag[lk] = etag
-            if ck not in self._payload:
-                self._payload[ck] = self._park(tenant, data)
-            return True
+        with M.span("nexus.cache.put", bytes=len(data)):
+            lk = self._lk(bucket, key)
+            ck = self._ck(tenant, data)
+            with self._lock:
+                if not self.state.write(lk, ck, nominal_size):
+                    return False
+                self._etag[lk] = etag
+                if ck not in self._payload:
+                    self._payload[ck] = self._park(tenant, data)
+                return True
 
     # ------------------------------------------------------- internals
 

@@ -268,7 +268,8 @@ class NexusClient:
             be = self._backend
             slot = be.arenas.get(self._ctx.tenant).alloc_wait(
                 max(len(Body), 1), timeout_s=be.alloc_timeout_s)
-            slot.write(Body)
+            with M.span("nexus.arena.write", bytes=len(Body)):
+                slot.write(Body)
             return be.submit_put(
                 self._ctx.tenant, self._ctx.cred_handle,
                 OutputHint(Bucket, Key), slot, self._ctx.invocation_id)
@@ -299,13 +300,12 @@ class BaselineClient:
     def __init__(self, remote: RemoteStorage, acct: M.CycleAccount,
                  lang: str = "py", sleep=None, *, sdk: str = "aws",
                  virtualized: bool = True, fault=None):
-        import time
         self._remote = remote
         self._acct = acct
         self._lang = lang
         self._sdk = sdk
         self._virtualized = virtualized
-        self._sleep = sleep or time.sleep
+        self._sleep = sleep
         #: FaultPlane tap (coupled variants): the fabric runs *inside*
         #: the guest, so a fabric crash kills the whole invocation —
         #: there is no supervisor underneath to hide it (§5).
@@ -322,7 +322,7 @@ class BaselineClient:
         else:
             cost = F.in_process_op_cost(self._sdk, self._lang, nominal)
         cost.charge(self._acct)
-        self._sleep(cost.total() / F.GHZ_MCYC_PER_S)
+        M.wait("fabric", cost.total() / F.GHZ_MCYC_PER_S, self._sleep)
 
     def get_object(self, Bucket: str, Key: str) -> dict:
         self._check_fault()

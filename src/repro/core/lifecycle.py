@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from dataclasses import dataclass
 
 from repro.core import fabric as F
@@ -41,7 +40,7 @@ class FunctionInstance:
     """One microVM hosting one function; executes invocations serially."""
 
     def __init__(self, workload: Workload, spec: SystemSpec,
-                 acct: M.CycleAccount, sleep=time.sleep,
+                 acct: M.CycleAccount, sleep=None,
                  fault_hooks=None):
         self.id = next(_iid)
         self.workload = workload
@@ -73,6 +72,10 @@ class FunctionInstance:
         the page-fault cycles of the dead attempt are still charged.
         Bounded at 2 failed attempts per restore so a long fault window
         cannot livelock a cold start."""
+        with M.span("nexus.restore"):
+            return self._restore()
+
+    def _restore(self) -> RestoreBreakdown:
         pages = F.working_set_pages_components(self.memory)
         bd = RestoreBreakdown(
             create_s=F.SNAPSHOT_FIXED_S,
@@ -82,9 +85,10 @@ class FunctionInstance:
         while (hooks is not None and hooks.restore_fail is not None
                and self.restore_retries < 2 and hooks.restore_fail()):
             self.restore_retries += 1
-            self._sleep(bd.total_s)          # the dead attempt's cost
+            # the dead attempt's cost
+            M.wait("restore", bd.total_s, self._sleep)
             self.acct.charge(M.HOST_KERNEL, pages * 2.0e-3)
-        self._sleep(bd.total_s)
+        M.wait("restore", bd.total_s, self._sleep)
         # page-fault handling burns host-kernel cycles + exits (no VM
         # boundary -> no exits for the wasm sandbox)
         self.acct.charge(M.HOST_KERNEL, pages * 2.0e-3)
@@ -115,9 +119,7 @@ class FunctionInstance:
         and account cycles + busy-guest crossings."""
         scaled = mcycles * self.spec.compute_scale
         modeled = scaled / F.GHZ_MCYC_PER_S
-        remaining = modeled - real_s
-        if remaining > 0:
-            self._sleep(remaining)
+        M.wait("compute_pad", modeled - real_s, self._sleep)
         self.acct.charge(M.GUEST_USER, scaled)
         # busy-guest exits (syscalls/GC/timers) that offloading can't remove
         if self.spec.virtualized:
@@ -131,7 +133,7 @@ class InstancePool:
     """Per-function pool with warm reuse and on-demand cold starts."""
 
     def __init__(self, workload: Workload, spec: SystemSpec,
-                 acct: M.CycleAccount, sleep=time.sleep,
+                 acct: M.CycleAccount, sleep=None,
                  max_instances: int = 64, fault_hooks=None):
         self.workload = workload
         self.spec = spec
@@ -190,7 +192,7 @@ class InstancePool:
             inst.restore()
             done.set()
 
-        threading.Thread(target=_run, daemon=True).start()
+        threading.Thread(target=M.carry(_run), daemon=True).start()
         return inst, done
 
     def scale_down(self, keep: int = 0) -> int:

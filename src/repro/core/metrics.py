@@ -1,4 +1,4 @@
-"""Cycle / crossing / memory accounting — the measurement plane.
+"""Cycle / crossing / memory accounting and spans — the measurement plane.
 
 The paper evaluates Nexus purely in CPU cycles (split across the four
 host/guest x user/kernel domains), KVM exit + vCPU-wakeup counts, and
@@ -9,11 +9,27 @@ TPU-framework analogue of a KVM exit is a host<->device / host<->storage
 boundary crossing, per DESIGN.md). The real threaded runtime and the
 discrete-event density simulator share this one accounting type, so
 every benchmark reports from the same books.
+
+Spans time the real work on the served path (`span`): each records its
+invocation, its own id and the id of the span that caused it, its
+thread, its start and end on ``time.monotonic_ns`` and the thread's CPU
+time over it, into a bounded in-memory ring (`SPANS`). Every sleep that
+stands in for a modeled cost goes through `wait`, as a ``nexus.wait``
+span, so what is modeled and what is real stay apart. When jax is
+already loaded, each span also enters ``jax.profiler.TraceAnnotation``
+and lands on the profiler's host plane, on the device trace's clock;
+this module never imports jax itself (the DES import chain stays
+jax-free).
 """
 from __future__ import annotations
 
+import contextvars
+import functools
+import itertools
+import sys
 import threading
-from collections import defaultdict
+import time
+from collections import defaultdict, deque
 from dataclasses import dataclass, field
 
 # Cycle domains (paper Fig. 2a / Fig. 8 notation).
@@ -51,13 +67,6 @@ class CycleAccount:
     def cross(self, kind: str, n: int = 1) -> None:
         with self._lock:
             self.crossings[kind] += n
-
-    def merge(self, other: "CycleAccount") -> None:
-        with self._lock:
-            for d, c in other.cycles.items():
-                self.cycles[d] += c
-            for k, n in other.crossings.items():
-                self.crossings[k] += n
 
     def total(self) -> float:
         with self._lock:
@@ -126,10 +135,114 @@ class LatencyTrace:
             xs = self._samples.get(label, [])
             return sum(xs) / len(xs) if xs else float("nan")
 
-    def count(self, label: str) -> int:
-        with self._lock:
-            return len(self._samples.get(label, []))
 
-    def labels(self) -> list[str]:
+# ------------------------------------------------------------------ spans
+
+#: spans the ring keeps; the oldest are dropped first
+SPAN_RING = 32768
+
+
+class Span:
+    """One piece of work on one thread; a context manager (`span`).
+
+    ``inv`` is the invocation id, taken from the enclosing span (of this
+    thread, or of the thread whose context `carry` copied) unless given;
+    ``parent`` is that enclosing span's id. ``t0``/``t1`` are
+    ``time.monotonic_ns`` and ``cpu`` the thread's CPU nanoseconds over
+    the span; ``attrs`` are small values (``bytes`` of a copy, ``cost``
+    of a modeled wait) that may be added while the span is open.
+    """
+
+    __slots__ = ("name", "inv", "id", "parent", "thread", "t0", "t1", "cpu",
+                 "attrs", "_c0", "_token", "_annotation")
+
+    def __init__(self, name: str, inv: str | None, attrs: dict):
+        self.name = name
+        self.inv = inv
+        self.attrs = attrs
+        self.parent = None
+
+    def __enter__(self) -> "Span":
+        up = _CURRENT.get()
+        if up is not None:
+            self.parent = up.id
+            if self.inv is None:
+                self.inv = up.inv
+        self.id = next(_SPAN_IDS)
+        self.thread = threading.get_ident()
+        self._token = _CURRENT.set(self)
+        self._annotation = _trace_annotation(self.name, self.attrs)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._c0 = time.thread_time_ns()
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.monotonic_ns()
+        self.cpu = time.thread_time_ns() - self._c0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        _CURRENT.reset(self._token)
+        self._token = None
+        SPANS.add(self)
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1 - self.t0) * 1e-9
+
+
+class SpanRing:
+    """The last `size` finished spans, in the order they ended."""
+
+    def __init__(self, size: int):
+        self._spans: deque[Span] = deque(maxlen=size)
+        self._lock = threading.Lock()
+
+    def add(self, s: Span) -> None:
         with self._lock:
-            return list(self._samples)
+            self._spans.append(s)
+
+    def since(self, t_ns: int = 0) -> list[Span]:
+        """The kept spans that started at or after `t_ns`
+        (``time.monotonic_ns``)."""
+        with self._lock:
+            return [s for s in self._spans if s.t0 >= t_ns]
+
+
+SPANS = SpanRing(SPAN_RING)
+_CURRENT: contextvars.ContextVar[Span | None] = contextvars.ContextVar(
+    "nexus_span", default=None)
+_SPAN_IDS = itertools.count(1)
+
+
+def span(name: str, inv: str | None = None, **attrs) -> Span:
+    """``with span("nexus.cache.get", bytes=n) as s:`` times the block."""
+    return Span(name, inv, attrs)
+
+
+def carry(fn):
+    """`fn` run in a copy of the caller's context: spans it opens on
+    another thread (a pool job, a plan branch, the guest) keep the
+    caller's invocation and name the caller's open span as parent."""
+    return functools.partial(contextvars.copy_context().run, fn)
+
+
+def wait(cost: str, seconds: float, sleep=None) -> None:
+    """Sleep `seconds` standing in for the modeled cost `cost`, as a
+    ``nexus.wait`` span: the one place the served path sleeps a model.
+    `sleep` (default ``time.sleep``, looked up per call) lets tests
+    stub the clock."""
+    if seconds <= 0:
+        return
+    with Span("nexus.wait", None, {"cost": cost, "s": seconds}):
+        (sleep or time.sleep)(seconds)
+
+
+def _trace_annotation(name: str, attrs: dict):
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name, **attrs)

@@ -22,6 +22,8 @@ from __future__ import annotations
 import threading
 import time
 
+from repro.core import metrics as M
+
 MBPS = 1024 * 1024 / 8          # bytes/s per Mbit/s
 DEFAULT_RATE_MBPS = 600.0
 
@@ -92,10 +94,9 @@ class TokenBucket:
         """Debit `nbytes`; return seconds the caller must delay (>= 0)."""
         return self.reserve_tx(nbytes).delay
 
-    def throttle(self, nbytes: int, sleep=time.sleep) -> float:
+    def throttle(self, nbytes: int, sleep=None) -> float:
         d = self.reserve(nbytes)
-        if d > 0:
-            sleep(d)
+        M.wait("throttle", d, sleep)
         return d
 
 

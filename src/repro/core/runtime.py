@@ -153,7 +153,7 @@ class _GuestRun:
             if self._started:
                 return
             self._started = True
-        threading.Thread(target=self._main, daemon=True).start()
+        threading.Thread(target=M.carry(self._main), daemon=True).start()
 
     def set_prefetch(self, handle) -> None:
         """The walker's ingress-prefetch action hands the guest stub its
@@ -207,6 +207,16 @@ class _GuestRun:
     def get_object(self, Bucket: str, Key: str) -> dict:
         self._close_segments()
         i = self._expect(Get)
+        with M.span("nexus.guest.get", op=i):
+            obj = self._get(i, Bucket, Key)
+        slot = obj.pop("_slot", None)
+        if slot is not None:
+            self._slots.append(slot)
+        self._events[f"fetch[{i}]"].set()
+        self._seg_t0 = time.monotonic()
+        return obj
+
+    def _get(self, i: int, Bucket: str, Key: str) -> dict:
         inv, spec = self._ctx, self._node.spec
         if spec.coupled:
             obj = inv.client.get_object(Bucket=Bucket, Key=Key)
@@ -225,11 +235,6 @@ class _GuestRun:
             obj = {"Body": memoryview(data), "ContentLength": len(data)}
         else:
             obj = inv.client.get_object(Bucket=Bucket, Key=Key)
-        slot = obj.pop("_slot", None)
-        if slot is not None:
-            self._slots.append(slot)
-        self._events[f"fetch[{i}]"].set()
-        self._seg_t0 = time.monotonic()
         return obj
 
     def put_object(self, Bucket: str, Key: str, Body) -> dict:
@@ -248,6 +253,14 @@ class _GuestRun:
                 f"unordered under async writeback",
                 subject=inv.w.name, op_index=self._oi)
         self._written.add((Bucket, Key))
+        with M.span("nexus.guest.put", op=k):
+            etag = self._put(k, Bucket, Key, Body)
+        self._events[f"write[{k}]"].set()
+        self._seg_t0 = time.monotonic()
+        return {"ETag": etag}
+
+    def _put(self, k: int, Bucket: str, Key: str, Body):
+        inv, node = self._ctx, self._node
         # handlers emit nominal-size outputs; the platform stores the
         # byte-scaled prefix while every cost model charges full size
         real = bytes(memoryview(Body)[:max(int(len(Body) * node.byte_scale),
@@ -266,9 +279,7 @@ class _GuestRun:
             etag = inv.client.put_object(Bucket=Bucket, Key=Key, Body=real,
                                          wait=True)
             self.etags[k] = etag
-        self._events[f"write[{k}]"].set()
-        self._seg_t0 = time.monotonic()
-        return {"ETag": etag}
+        return etag
 
     # ------------------------------------------------------------ matching
 
@@ -332,7 +343,8 @@ class _PlanRun:
 
     Each group runs as soon as its dependencies complete; parallel
     branches (prefetch vs restore) get real threads; barriers fire as
-    completion hooks. Per-group wall time is recorded as the breakdown.
+    completion hooks. Each group runs in a ``nexus.group`` span, whose
+    duration is the group's entry in the breakdown.
     """
 
     def __init__(self, program: PlanProgram, actions: dict,
@@ -359,7 +371,7 @@ class _PlanRun:
     def run(self) -> dict[str, float]:
         roots = self._program.group_roots
         for gi in roots[1:]:
-            threading.Thread(target=self._chain, args=(gi,),
+            threading.Thread(target=M.carry(self._chain), args=(gi,),
                              daemon=True).start()
         self._chain(roots[0])
         if not self._finished.wait(timeout=self._stall):
@@ -378,9 +390,10 @@ class _PlanRun:
                     return
                 self._started[gi] = True
                 self._active += 1
-            t0 = time.monotonic()
+            name = self._names[gi]
             try:
-                self._actions[gi](self._ctx)
+                with M.span("nexus.group", group=name) as s:
+                    self._actions[gi](self._ctx)
             except BaseException as e:              # noqa: BLE001
                 with self._lock:
                     self._active -= 1
@@ -389,7 +402,7 @@ class _PlanRun:
                     if self._active == 0:
                         self._finished.set()
                 return
-            self.breakdown[self._names[gi]] = time.monotonic() - t0
+            self.breakdown[name] = s.seconds
             hook = self._hooks.get(gi)
             if hook is not None:
                 hook()
@@ -410,7 +423,7 @@ class _PlanRun:
                     if need[si] == 0 and not self._started[si]:
                         ready.append(si)
             for g in ready[1:]:
-                threading.Thread(target=self._chain, args=(g,),
+                threading.Thread(target=M.carry(self._chain), args=(g,),
                                  daemon=True).start()
             gi = ready[0] if ready else None
 
@@ -685,12 +698,14 @@ class WorkerNode:
     def _run(self, w: Workload, inv_id: str, event: dict,
              pace_s: float = 0.0) -> InvocationResult:
         t0 = time.monotonic()
-        if pace_s > 0.0:
-            # admission pacing: the bucket said "queue" — latency is
-            # measured from submission, so the wait shows up in it
-            time.sleep(pace_s)
         try:
-            return self._run_inner(w, inv_id, event, t0)
+            with M.span("nexus.invoke", inv=inv_id, function=w.name) as s:
+                # admission pacing: the bucket said "queue" — latency is
+                # measured from submission, so the wait shows up in it
+                M.wait("pace", pace_s)
+                res = self._run_inner(w, inv_id, event, t0)
+                s.attrs["cold"] = res.cold
+                return res
         finally:
             with self._quiesce:
                 self._inflight -= 1
@@ -870,7 +885,7 @@ class WorkerNode:
         else:
             # wasm: Faabric scheduler hop + sandbox-bootstrap page faults
             self.acct.charge(M.HOST_KERNEL, F.FAABRIC_KERNEL_MCYC)
-            time.sleep(spec.dispatch_s)
+            M.wait("dispatch", spec.dispatch_s)
 
     def _act_connect(self, ctx: _Invocation) -> None:
         # per-VM storage connection setup (the 'Add Server' cold-start

@@ -13,7 +13,6 @@ is issued if the first exceeds the hedge threshold, first response wins
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass
 
 from repro.core import metrics as M
@@ -137,7 +136,7 @@ class RemoteStorage:
 
     def __init__(self, store: ObjectStore, transport: TransportSpec | str,
                  acct: M.CycleAccount, *, hedge_after_s: float | None = None,
-                 faults: FaultPlan | None = None, sleep=time.sleep,
+                 faults: FaultPlan | None = None, sleep=None,
                  cost_scale: float = 1.0):
         self.store = store
         self.transport = (TRANSPORTS[transport]
@@ -198,7 +197,7 @@ class RemoteStorage:
             t = min(t, self.hedge_after_s
                     + self.transport.transfer_latency(
                         int(len(data) * self.cost_scale)))
-        self._sleep(t)
+        M.wait("transport", t, self._sleep)
         self.transport.charge_transfer(self.acct,
                                        int(len(data) * self.cost_scale))
         return data, meta
@@ -207,11 +206,11 @@ class RemoteStorage:
         op = self._next_op()
         self._maybe_fail(op)
         nbytes = len(data)
-        self._sleep(self._service_time(nbytes, op))
+        M.wait("transport", self._service_time(nbytes, op), self._sleep)
         self.transport.charge_transfer(self.acct,
                                        int(nbytes * self.cost_scale))
         return self.store.put(bucket, key, bytes(data))
 
     def head(self, bucket: str, key: str) -> ObjectMeta:
-        self._sleep(self.transport.base_latency_s)
+        M.wait("transport", self.transport.base_latency_s, self._sleep)
         return self.store.head(bucket, key)

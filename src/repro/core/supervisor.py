@@ -18,9 +18,9 @@ every crash signal produces exactly one restart.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Callable
 
+from repro.core import metrics as M
 from repro.core.backend import NexusBackend
 
 
@@ -33,7 +33,7 @@ class Supervisor:
         #: restart cost — public so fault schedules can retune it
         self.restart_delay_s = restart_delay_s
         self._backend = factory()
-        self._running = False
+        self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self.restarts = 0
         self._lock = threading.Lock()
@@ -45,16 +45,16 @@ class Supervisor:
             return self._backend
 
     def start(self) -> None:
-        self._running = True
+        self._stop.clear()
         self._thread = threading.Thread(target=self._watch, daemon=True,
                                         name="nexus-supervisor")
         self._thread.start()
 
     def _watch(self) -> None:
-        while self._running:
+        while not self._stop.is_set():
             be = self.backend
             if not be.alive:
-                time.sleep(self.restart_delay_s)     # restart cost
+                M.wait("restart", self.restart_delay_s)
                 fresh = self._factory()
                 with self._lock:
                     # carry over arena registry? NO — crash-only: fresh
@@ -67,7 +67,7 @@ class Supervisor:
                         fresh.crash()
                     self._backend = fresh
                 self.restarts += 1
-            time.sleep(self._poll)
+            self._stop.wait(self._poll)
 
     def kill_backend(self) -> None:
         """Fault injection entry point used by tests/benchmarks.
@@ -84,7 +84,7 @@ class Supervisor:
             be.crash()
 
     def stop(self) -> None:
-        self._running = False
+        self._stop.set()
         if self._thread:
             self._thread.join(timeout=1.0)
         self.backend.shutdown()

@@ -17,7 +17,6 @@ to `CycleAccount`). Constants are calibrated for the paper's testbed
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 from repro.core import metrics as M
@@ -74,18 +73,3 @@ RDMA = TransportSpec(
 
 TRANSPORTS = {"tcp": TCP, "rdma": RDMA}
 
-
-class TimeSource:
-    """Pluggable clock: real wall clock (threaded runtime) or virtual
-    (discrete-event density simulator). `sleep` must be called off the
-    simulator's critical sections."""
-
-    def now(self) -> float:
-        return time.monotonic()
-
-    def sleep(self, seconds: float) -> None:
-        if seconds > 0:
-            time.sleep(seconds)
-
-
-REAL_TIME = TimeSource()

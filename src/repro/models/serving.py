@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import functools
 
+from repro.core import metrics as M
 from repro.core.calibrate import (LLM_WEIGHT_SHARDS, ML_ROLES, MOE_SHARDS,
                                   serving_shapes, shard_bytes)
 from repro.models import serialize
@@ -223,56 +224,85 @@ def seed_payloads(scenario: str, cfg=None) -> list[bytes]:
 # `cfg` is the deployment's `Workload.model` (None: the role's SMOKE
 # config). Each core decodes its own copy of the weights from the bytes
 # `ctx.storage` handed it; that copy is the only one on the device.
+#
+# Spans: ``nexus.handler.decode`` per input (joining shards, then
+# `serialize.loads` with its host-to-device copy), ``nexus.handler.step``
+# from the jitted call until the outputs the encode reads are ready,
+# ``nexus.handler.encode`` (`serialize.dumps`). The step waits only on
+# what the encode would wait on anyway.
 
-def _load_params(b: dict, blob):
-    return serialize.loads(b["structs"]["params"], blob)
+def _decode(shapes, *bodies):
+    with M.span("nexus.handler.decode",
+                bytes=sum(len(b) for b in bodies)):
+        return serialize.loads(
+            shapes, bodies[0] if len(bodies) == 1 else b"".join(bodies))
+
+
+def _ready(tree):
+    import jax
+    return jax.block_until_ready(tree)
+
+
+def _encode(tree) -> bytes:
+    with M.span("nexus.handler.encode") as s:
+        out = serialize.dumps(tree)
+        s.attrs["bytes"] = len(out)
+    return out
 
 
 def llm_cold(shard_bodies, prompt_body, cfg=None) -> bytes:
     """Assemble weights from shards, prefill the prompt, take one decode
     step; the durable output is the step's logits."""
     b = bundle(role_config("llm", cfg))
-    params = _load_params(b, b"".join(shard_bodies))
-    tokens = serialize.loads(b["structs"]["prompt"], prompt_body)
-    logits, cache = b["prefill"](params, {"tokens": tokens})
-    logits2, _ = b["decode"](params, cache, next_token(logits))
-    return serialize.dumps(logits2)
+    params = _decode(b["structs"]["params"], *shard_bodies)
+    tokens = _decode(b["structs"]["prompt"], prompt_body)
+    with M.span("nexus.handler.step"):
+        logits, cache = b["prefill"](params, {"tokens": tokens})
+        logits2, _ = _ready(b["decode"](params, cache, next_token(logits)))
+    return _encode(logits2)
 
 
 def llm_prefill(params_body, prompt_body, cfg=None) -> bytes:
     """Prefill: the durable output is the serialized KV cache the decode
     tier would consume."""
     b = bundle(role_config("llm", cfg))
-    params = _load_params(b, params_body)
-    tokens = serialize.loads(b["structs"]["prompt"], prompt_body)
-    _, cache = b["prefill"](params, {"tokens": tokens})
-    return serialize.dumps(cache)
+    params = _decode(b["structs"]["params"], params_body)
+    tokens = _decode(b["structs"]["prompt"], prompt_body)
+    with M.span("nexus.handler.step"):
+        _, cache = b["prefill"](params, {"tokens": tokens})
+        _ready(cache)
+    return _encode(cache)
 
 
 def llm_decode(params_body, kv_body, cfg=None) -> tuple[bytes, int]:
     """One decode step: deserialize (cache, token), advance the model,
     return (serialized updated cache, next token id)."""
     b = bundle(role_config("llm", cfg))
-    params = _load_params(b, params_body)
-    cache, token = serialize.loads(
+    params = _decode(b["structs"]["params"], params_body)
+    cache, token = _decode(
         (b["structs"]["decode_cache"], b["structs"]["step_token"]), kv_body)
-    logits, cache2 = b["decode"](params, cache, token)
-    return serialize.dumps(cache2), int(next_token(logits)[0, 0])
+    with M.span("nexus.handler.step"):
+        logits, cache2 = _ready(b["decode"](params, cache, token))
+    return _encode(cache2), int(next_token(logits)[0, 0])
 
 
 def emb_encode(params_body, tokens_body, cfg=None) -> bytes:
     """Batch encode: final-position logits as the embedding vectors."""
     b = bundle(role_config("emb", cfg))
-    params = _load_params(b, params_body)
-    tokens = serialize.loads(b["structs"]["enc_tokens"], tokens_body)
-    logits, _ = b["prefill"](params, {"tokens": tokens})
-    return serialize.dumps(logits)
+    params = _decode(b["structs"]["params"], params_body)
+    tokens = _decode(b["structs"]["enc_tokens"], tokens_body)
+    with M.span("nexus.handler.step"):
+        logits, _ = b["prefill"](params, {"tokens": tokens})
+        _ready(logits)
+    return _encode(logits)
 
 
 def moe_infer(shard_bodies, cfg=None) -> bytes:
     """Expert-shard fan-in: reassemble the MoE params from the fetched
     shards, run the fixed prompt through the router + top-k experts."""
     b = bundle(role_config("moe", cfg))
-    params = _load_params(b, b"".join(shard_bodies))
-    logits, _ = b["prefill"](params, {"tokens": _prompt_tokens(b)})
-    return serialize.dumps(logits)
+    params = _decode(b["structs"]["params"], *shard_bodies)
+    with M.span("nexus.handler.step"):
+        logits, _ = b["prefill"](params, {"tokens": _prompt_tokens(b)})
+        _ready(logits)
+    return _encode(logits)
