@@ -18,6 +18,8 @@ import threading
 import time
 from dataclasses import dataclass
 
+import numpy as np
+
 from repro.core import metrics as M
 
 MB = 1024 * 1024
@@ -48,11 +50,15 @@ class Slot:
         return self.arena._buf_view[self.offset:self.offset + self.used]
 
     def write(self, data, at: int = 0) -> int:
-        """Place bytes into the slot (backend fill / frontend output)."""
+        """Place bytes into the slot (backend fill / frontend output).
+        The copy runs in `np.copyto`, which releases the GIL: a
+        multi-GB payload never stalls the node's other threads."""
         n = len(data)
         if at + n > self.size:
             raise ArenaError(f"payload {at + n}B exceeds slot {self.size}B")
-        self.arena._buf_view[self.offset + at:self.offset + at + n] = data
+        dst = np.frombuffer(self.arena._buf_view, np.uint8, n,
+                            self.offset + at)
+        np.copyto(dst, np.frombuffer(data, np.uint8))
         self.used = max(self.used, at + n)
         return n
 

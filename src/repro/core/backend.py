@@ -176,13 +176,12 @@ class NexusBackend:
     def _authorized_get(self, tenant: str, cred: str, bucket: str,
                         key: str, *, hinted: bool = True,
                         use_cache: bool = True) -> bytes:
-        """Authorized GET through the SharedCache plane. A validated
-        hit is served from the host arena tier: no remote trip, no SDK
-        cycles, no S3 rate-limit spend — only the modeled arena copy
-        time (the same `hit_duration_s` the DES charges). A miss takes
-        the full remote path and offers the bytes back for admission
-        (`hinted` = the GET was hint-promoted at ingress;
-        ``use_cache=False`` is the per-GET opt-out header)."""
+        """Authorized GET through the SharedCache plane, as bytes (the
+        streaming fallback's path). A validated hit hands out a copy of
+        the parked payload; a miss takes the full remote path and offers
+        the bytes back for admission (`hinted` = the GET was
+        hint-promoted at ingress; ``use_cache=False`` is the per-GET
+        opt-out header)."""
         self.tokens.authorize(cred, bucket, "get")
         self.connection_setup(bucket)
         cache = self.cache if use_cache else None
@@ -190,10 +189,52 @@ class NexusBackend:
             data = cache.get(tenant, bucket, key, self.remote.store,
                              hinted=hinted)
             if data is not None:
-                self.stats["cache_hits"] += 1
-                M.wait("hit", cache.spec.hit_duration_s(
-                    int(len(data) * self.remote.cost_scale)))
+                self._served_hit(cache, len(data))
                 return data
+        return self._remote_get(tenant, bucket, key, cache, hinted)
+
+    def _fetch_into(self, tenant: str, cred: str, bucket: str, key: str,
+                    *, hinted: bool, use_cache: bool) -> Slot:
+        """`_authorized_get` landing in an exactly-sized slot of the
+        tenant's arena. A validated hit is copied there once, straight
+        from the parked payload (`SharedCache.get_into`); a miss takes
+        the remote path and copies the fetched bytes. Arena pressure is
+        transient: the allocation stalls for reclaim rather than
+        failing the fetch outright (§4.3.1)."""
+        self.tokens.authorize(cred, bucket, "get")
+        self.connection_setup(bucket)
+        arena = self.arenas.get(tenant)
+
+        def alloc(size: int) -> Slot:
+            return arena.alloc_wait(max(size, 1),
+                                    timeout_s=self.alloc_timeout_s)
+
+        cache = self.cache if use_cache else None
+        if cache is not None:
+            slot = cache.get_into(tenant, bucket, key, self.remote.store,
+                                  alloc, hinted=hinted)
+            if slot is not None:
+                self._served_hit(cache, slot.used)
+                return slot
+        data = self._remote_get(tenant, bucket, key, cache, hinted)
+        slot = alloc(len(data))
+        with M.span("nexus.arena.write", bytes=len(data)):
+            slot.write(data)
+        # RDMA: NIC DMAs straight into the registered arena — charged
+        # inside the transport model (zero host-kernel).
+        return slot
+
+    def _served_hit(self, cache: SharedCache, nbytes: int) -> None:
+        """A hit is served from the host arena tier: no remote trip,
+        no SDK cycles, no S3 rate-limit spend — only the modeled arena
+        copy time (the same `hit_duration_s` the DES charges)."""
+        self.stats["cache_hits"] += 1
+        M.wait("hit", cache.spec.hit_duration_s(
+            int(nbytes * self.remote.cost_scale)))
+
+    def _remote_get(self, tenant: str, bucket: str, key: str,
+                    cache: SharedCache | None, hinted: bool) -> bytes:
+        """The miss path: remote GET, admission offer, SDK, throttle."""
         # bytes and etag come from ONE atomic store snapshot: a PUT
         # committing during the modeled transfer must never let the
         # fill bind the old bytes to the new version's etag (that
@@ -226,19 +267,9 @@ class NexusBackend:
                     self._check_alive()
                     if pre_connect is not None:
                         self.connection_setup(pre_connect)
-                    data = self._authorized_get(tenant, cred, hint.bucket,
-                                                hint.key, hinted=True,
-                                                use_cache=hint.cacheable)
-                    size = len(data)
-                    # arena pressure is transient: stall for reclaim
-                    # rather than failing the fetch outright (§4.3.1)
-                    slot = self.arenas.get(tenant).alloc_wait(
-                        max(size, 1), timeout_s=self.alloc_timeout_s)
-                    with M.span("nexus.arena.write", bytes=size):
-                        slot.write(data)
-                    # RDMA: NIC DMAs straight into the registered arena —
-                    # charged inside the transport model (zero host-kernel).
-                    handle.slot = slot
+                    handle.slot = self._fetch_into(
+                        tenant, cred, hint.bucket, hint.key, hinted=True,
+                        use_cache=hint.cacheable)
             except BaseException as e:      # noqa: BLE001 — propagated
                 handle.error = e
             finally:
@@ -253,13 +284,8 @@ class NexusBackend:
         self._check_alive()
         self.stats["sync_gets"] += 1
         with M.span("nexus.backend.fetch_sync"):
-            data = self._authorized_get(tenant, cred, bucket, key,
-                                        hinted=hinted, use_cache=cacheable)
-            slot = self.arenas.get(tenant).alloc_wait(
-                max(len(data), 1), timeout_s=self.alloc_timeout_s)
-            with M.span("nexus.arena.write", bytes=len(data)):
-                slot.write(data)
-        return slot
+            return self._fetch_into(tenant, cred, bucket, key,
+                                    hinted=hinted, use_cache=cacheable)
 
     def fetch_stream(self, tenant: str, cred: str, bucket: str, key: str,
                      buf: CircularBuffer, chunk: int = 256 * 1024) -> None:

@@ -52,6 +52,10 @@ module adds the host cache as three layers:
     after the full byte copy completes, and hits hand out immutable
     copies — a backend crash can abandon a fill, never expose half of
     one;
+  - never reused under a reader: `get_into` pins the hit's content key
+    for its one copy into the tenant's slot, made outside the lock. A
+    payload freed while pinned leaves the lookup tables at once, and
+    its arena slot goes back only at the last unpin;
   - write-through only after durability: `put` is called by the
     backend strictly after the remote PUT committed.
 """
@@ -323,7 +327,11 @@ class SharedCache:
             arena_mb if arena_mb is not None else spec.capacity_mb)
         self._payload: dict[str, bytes | Slot] = {}   # ck -> parked bytes
         self._etag: dict[str, int] = {}               # lk -> captured etag
+        self._pins: dict[str, int] = {}               # ck -> copies reading it
+        self._doomed: dict[str, bytes | Slot] = {}    # ck -> freed while pinned
         self.arena_fallbacks = 0
+        self.direct_hits = 0       # served by `get_into`
+        self.copied_hits = 0       # served by `get`
 
     @staticmethod
     def _lk(bucket: str, key: str) -> str:
@@ -340,12 +348,58 @@ class SharedCache:
         """Cache-consulting GET. Returns immutable payload bytes on a
         validated hit, ``None`` on any miss (the caller then takes the
         remote path and offers the result back via `fill`)."""
-        with M.span("nexus.cache.get") as s:
+        with M.span("nexus.cache.get", direct=False) as s:
             data = self._get(bucket, key, store)
             s.attrs["bytes"] = len(data) if data is not None else 0
         return data
 
     def _get(self, bucket: str, key: str, store) -> bytes | None:
+        with self._lock:
+            hit = self._lookup(bucket, key, store)
+            if hit is None:
+                return None
+            self.copied_hits += 1
+            parked = hit[1]
+            if isinstance(parked, Slot):
+                return bytes(parked.view())       # copy under the lock
+            return parked
+
+    def get_into(self, tenant: str, bucket: str, key: str, store, alloc,
+                 *, hinted: bool = True) -> Slot | None:
+        """Cache-consulting GET straight into the caller's arena. On a
+        validated hit, `alloc(size)` (the tenant arena's allocator)
+        gives the slot and the parked payload is copied into it once,
+        outside the cache lock and the GIL; returns that slot. A miss
+        returns ``None`` and allocates nothing. The lookup and its
+        revalidation are `get`'s, so the `CacheState` counters are too."""
+        with M.span("nexus.cache.get", direct=True) as s:
+            with self._lock:
+                hit = self._lookup(bucket, key, store)
+                if hit is not None:
+                    ck, parked = hit
+                    self._pins[ck] = self._pins.get(ck, 0) + 1
+                    self.direct_hits += 1
+            s.attrs["bytes"] = 0 if hit is None else (
+                parked.used if isinstance(parked, Slot) else len(parked))
+        if hit is None:
+            return None
+        try:
+            src = parked.view() if isinstance(parked, Slot) else parked
+            slot = alloc(len(src))
+            try:
+                with M.span("nexus.arena.write", bytes=len(src)):
+                    slot.write(src)
+            except BaseException:
+                slot.release()
+                raise
+            return slot
+        finally:
+            self._unpin(ck)
+
+    def _lookup(self, bucket: str, key: str,
+                store) -> tuple[str, bytes | Slot] | None:
+        """One `CacheState.lookup` with etag revalidation; the hit's
+        content key and parked payload. Caller holds the lock."""
         lk = self._lk(bucket, key)
 
         def _valid(lk_: str, _ck: str) -> bool:
@@ -355,17 +409,14 @@ class SharedCache:
                 return False                      # object gone: stale
             return self._etag.get(lk_) == meta.etag
 
-        with self._lock:
-            ck = self.state.lookup(lk, valid=_valid)
-            if ck is None:
-                return None
-            parked = self._payload.get(ck)
-            if parked is None:                    # defensive: payload lost
-                self.state.invalidate(lk)
-                return None
-            if isinstance(parked, Slot):
-                return bytes(parked.view())       # copy under the lock
-            return parked
+        ck = self.state.lookup(lk, valid=_valid)
+        if ck is None:
+            return None
+        parked = self._payload.get(ck)
+        if parked is None:                        # defensive: payload lost
+            self.state.invalidate(lk)
+            return None
+        return ck, parked
 
     def fill(self, tenant: str, bucket: str, key: str, data: bytes,
              nominal_size: int, *, hinted: bool, etag: int) -> bool:
@@ -385,7 +436,7 @@ class SharedCache:
                     return False
                 self._etag[lk] = etag
                 if ck not in self._payload:
-                    self._payload[ck] = self._park(tenant, data)
+                    self._payload[ck] = self._repark(ck, tenant, data)
                 return True
 
     def put(self, tenant: str, bucket: str, key: str, data: bytes,
@@ -399,10 +450,17 @@ class SharedCache:
                     return False
                 self._etag[lk] = etag
                 if ck not in self._payload:
-                    self._payload[ck] = self._park(tenant, data)
+                    self._payload[ck] = self._repark(ck, tenant, data)
                 return True
 
     # ------------------------------------------------------- internals
+
+    def _repark(self, ck: str, tenant: str, data) -> bytes | Slot:
+        """The payload to publish under `ck`: one freed while a copy
+        still pins it holds the same bytes (the key is their hash), so
+        it is taken back instead of parked again."""
+        doomed = self._doomed.pop(ck, None)
+        return doomed if doomed is not None else self._park(tenant, data)
 
     def _park(self, tenant: str, data) -> bytes | Slot:
         """Copy payload bytes into the arena tier; publication happens
@@ -425,6 +483,18 @@ class SharedCache:
 
     def _drop_payload(self, ck: str) -> None:
         parked = self._payload.pop(ck, None)
+        if ck in self._pins:
+            self._doomed[ck] = parked             # released at the last unpin
+        elif isinstance(parked, Slot):
+            parked.release()
+
+    def _unpin(self, ck: str) -> None:
+        with self._lock:
+            self._pins[ck] -= 1
+            if self._pins[ck]:
+                return
+            del self._pins[ck]
+            parked = self._doomed.pop(ck, None)
         if isinstance(parked, Slot):
             parked.release()
 
@@ -437,6 +507,8 @@ class SharedCache:
         snap = self.state.snapshot()
         with self._lock:
             snap["arena_fallbacks"] = self.arena_fallbacks
+            snap["direct_hits"] = self.direct_hits
+            snap["copied_hits"] = self.copied_hits
             snap["arena_bytes"] = sum(
                 s.size for s in self._payload.values()
                 if isinstance(s, Slot))

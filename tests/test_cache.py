@@ -17,9 +17,14 @@ Four layers of evidence:
   the eviction-pressure regime, and the ml_suite KV/weights chains
   become hits after the first invocation on a node in BOTH executors.
 """
+import os
+import sys
+import threading
+
 import pytest
 
 from repro.core import workloads as W
+from repro.core.arena import ArenaError, Slot, TenantArena
 from repro.core.cache import CacheSpec, CacheState, SharedCache
 from repro.core.des import DensitySimulator, _build_bundle, cache_overlay
 from repro.core.runtime import WorkerNode
@@ -332,6 +337,238 @@ class TestSharedCache:
         assert shared.snapshot()["dedup_bytes"] == 4096
         assert private.snapshot()["unique_content"] == 2
         assert private.snapshot()["dedup_bytes"] == 0
+
+
+# ---------------------------------------------- SharedCache.get_into
+
+class TestSharedCacheGetInto:
+    """The hit verb that copies the parked payload once, straight into
+    the caller's arena slot, pinning the content key for the copy."""
+
+    SIZE = 4096
+
+    def _filled(self, arena_mb=None, capacity_mb=1.0, nominal=SIZE,
+                data=b"x" * SIZE):
+        store = ObjectStore()
+        store.put("in", "k", data)
+        cache = SharedCache(CacheSpec(capacity_mb=capacity_mb),
+                            arena_mb=arena_mb)
+        v, meta = store.get_with_meta("in", "k")
+        assert cache.fill("t", "in", "k", v, nominal, hinted=True,
+                          etag=meta.etag)
+        return store, cache
+
+    @staticmethod
+    def _cache_arena(cache):
+        return cache._arenas.get("__cache__")
+
+    @pytest.mark.parametrize("arena_mb,kind", [(1.0, Slot),
+                                               (0.001, bytes)])
+    def test_hit_lands_the_same_bytes_as_get(self, arena_mb, kind):
+        store, cache = self._filled(arena_mb=arena_mb)
+        (parked,) = cache._payload.values()
+        assert isinstance(parked, kind)
+        tenant = TenantArena("t", capacity_mb=1.0)
+        slot = cache.get_into("t", "in", "k", store, tenant.alloc)
+        assert slot.arena is tenant and slot.used == self.SIZE
+        assert bytes(slot.view()) == cache.get("t", "in", "k", store)
+        snap = cache.snapshot()
+        assert (snap["direct_hits"], snap["copied_hits"]) == (1, 1)
+        assert cache._pins == {}
+
+    def test_writing_the_slot_never_changes_a_later_hit(self):
+        store, cache = self._filled()
+        tenant = TenantArena("t", capacity_mb=1.0)
+        slot = cache.get_into("t", "in", "k", store, tenant.alloc)
+        slot.write(b"z" * self.SIZE)
+        again = cache.get_into("t", "in", "k", store, tenant.alloc)
+        assert bytes(again.view()) == b"x" * self.SIZE
+        assert cache.get("t", "in", "k", store) == b"x" * self.SIZE
+
+    @pytest.mark.parametrize("arena_mb", [0.25, 16.0])
+    def test_state_counters_match_get(self, arena_mb):
+        """Hits, misses, a stale invalidation and evictions drive
+        `CacheState` identically through either hit verb."""
+        def drive(verb):
+            store = ObjectStore()
+            cache = SharedCache(CacheSpec(capacity_mb=2.0),
+                                arena_mb=arena_mb)
+            tenant = TenantArena("t", capacity_mb=4.0)
+
+            def hit(key):
+                if verb == "get":
+                    return cache.get("t", "in", key, store)
+                slot = cache.get_into("t", "in", key, store, tenant.alloc)
+                if slot is None:
+                    return None
+                data = bytes(slot.view())
+                slot.release()
+                return data
+
+            for i in range(3):
+                store.put("in", f"k{i}", bytes([i]) * (256 * 1024))
+            for key in ("k0", "k1", "k0", "k1", "!k0", "k0", "k2", "k1",
+                        "k0"):
+                if key.startswith("!"):          # a new version lands
+                    store.put("in", key[1:], b"new" * 1024)
+                    continue
+                if hit(key) is None:
+                    data, meta = store.get_with_meta("in", key)
+                    cache.fill("t", "in", key, data, 768 * 1024,
+                               hinted=True, etag=meta.etag)
+                    assert hit(key) == data
+            assert tenant.allocated == 0
+            return cache.state.snapshot()
+
+        got, into = drive("get"), drive("get_into")
+        assert got == into
+        assert got["evictions"] > 0 and got["stale_invalidations"] == 1
+
+    def test_miss_returns_none_and_allocates_nothing(self):
+        store, cache = self._filled()
+        store.put("in", "other", b"o" * 64)
+        calls = []
+        assert cache.get_into("t", "in", "other", store,
+                              calls.append) is None
+        assert calls == []
+        assert cache.snapshot()["direct_hits"] == 0
+        assert cache._pins == {}
+
+    @pytest.mark.parametrize("how", ["evict", "invalidate", "rewrite"])
+    def test_entry_freed_while_pinned(self, how, monkeypatch):
+        """Freed mid-copy: never served again, its parked slot goes
+        back exactly once, at the unpin, and the copy reads the old
+        bytes whole."""
+        store, cache = self._filled(nominal=600 * 1024)
+        arena = self._cache_arena(cache)
+        (parked,) = cache._payload.values()
+        frees = []
+        real_free = arena._free
+        monkeypatch.setattr(arena, "_free",
+                            lambda s: (frees.append(s), real_free(s)))
+        tenant = TenantArena("t", capacity_mb=1.0)
+
+        def alloc(n):
+            if how == "evict":
+                store.put("in", "k2", b"y" * 1024)
+                cache.fill("t", "in", "k2", store.get("in", "k2"),
+                           600 * 1024, hinted=True,
+                           etag=store.head("in", "k2").etag)
+                assert cache.snapshot()["evictions"] == 1
+            elif how == "invalidate":
+                store.put("in", "k", b"y" * self.SIZE)
+            else:
+                meta = store.put("in", "k", b"y" * self.SIZE)
+                assert cache.put("t", "in", "k", b"y" * self.SIZE,
+                                 600 * 1024, meta.etag)
+            # no lookup serves the freed payload, and its slot is held
+            seen = cache.get("t", "in", "k", store)
+            assert seen in (None, b"y" * self.SIZE)
+            assert frees == [] and not parked.released
+            return tenant.alloc(n)
+
+        slot = cache.get_into("t", "in", "k", store, alloc)
+        assert bytes(slot.view()) == b"x" * self.SIZE
+        assert frees == [parked] and parked.released
+        assert cache._pins == {} and cache._doomed == {}
+        assert arena.allocated == sum(
+            p.size for p in cache._payload.values() if isinstance(p, Slot))
+
+    def test_refill_while_pinned_takes_the_parked_slot_back(self):
+        """The content key names the bytes: a payload freed and filled
+        again during a copy is published again, not parked twice."""
+        store, cache = self._filled()
+        arena = self._cache_arena(cache)
+        (parked,) = cache._payload.values()
+        before = arena.allocated
+        tenant = TenantArena("t", capacity_mb=1.0)
+        data = store.get("in", "k")
+
+        def alloc(n):
+            store.put("in", "k", data)           # same bytes, new etag
+            assert cache.get("t", "in", "k", store) is None
+            assert cache.fill("t", "in", "k", data, self.SIZE, hinted=True,
+                              etag=store.head("in", "k").etag)
+            return tenant.alloc(n)
+
+        slot = cache.get_into("t", "in", "k", store, alloc)
+        assert bytes(slot.view()) == data
+        assert list(cache._payload.values()) == [parked]
+        assert not parked.released and arena.allocated == before
+        assert cache.get("t", "in", "k", store) == data
+
+    @pytest.mark.parametrize("fault", ["alloc", "copy"])
+    def test_failed_hit_leaves_no_pin_and_no_slot(self, fault):
+        """An exhausted arena (or a slot the payload overflows) fails
+        the hit; the pin and any tenant slot go back."""
+        store, cache = self._filled()
+        tenant = TenantArena("t", capacity_mb=1.0)
+        if fault == "alloc":
+            tenant.alloc(tenant.capacity)        # exhausted
+
+            def alloc(n):
+                return tenant.alloc(n)
+        else:
+            def alloc(n):
+                return tenant.alloc(n // 2)       # too small: write fails
+        before = tenant.allocated
+        with pytest.raises(ArenaError):
+            cache.get_into("t", "in", "k", store, alloc)
+        assert cache._pins == {}
+        assert tenant.allocated == before
+        assert cache.get("t", "in", "k", store) == b"x" * self.SIZE
+
+    def test_concurrent_hits_and_frees_keep_the_arena_exact(self):
+        """More threads than cores hit, refill, evict and invalidate
+        three keys at once: every hit reads its own key's bytes whole,
+        and afterwards no pin, deferred free or leaked slot is left."""
+        store = ObjectStore()
+        payloads = {f"k{i}": bytes([i + 1]) * (64 * self.SIZE)
+                    for i in range(3)}
+        for key, data in payloads.items():
+            store.put("in", key, data)
+        cache = SharedCache(CacheSpec(capacity_mb=2.0))   # two of three fit
+        tenant = TenantArena("t", capacity_mb=8.0)
+        errors = []
+
+        def worker(w):
+            try:
+                for i in range(150):
+                    key = f"k{(w + i) % 3}"
+                    if i % 17 == w % 17:         # same bytes, new etag
+                        store.put("in", key, payloads[key])
+                    slot = cache.get_into("t", "in", key, store,
+                                          tenant.alloc)
+                    if slot is None:
+                        data, meta = store.get_with_meta("in", key)
+                        cache.fill("t", "in", key, data, 700 * 1024,
+                                   hinted=True, etag=meta.etag)
+                        continue
+                    if bytes(slot.view()) != payloads[key]:
+                        errors.append(key)
+                    slot.release()
+            except BaseException as e:            # noqa: BLE001 — reported
+                errors.append(e)
+
+        threads = [threading.Thread(target=worker, args=(w,))
+                   for w in range((os.cpu_count() or 4) + 4)]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        snap = cache.snapshot()
+        assert snap["direct_hits"] > 0 and snap["evictions"] > 0
+        assert cache._pins == {} and cache._doomed == {}
+        assert tenant.allocated == 0
+        assert self._cache_arena(cache).allocated == sum(
+            p.size for p in cache._payload.values() if isinstance(p, Slot))
 
 
 # ------------------------------------------------- PlanVerify overlay

@@ -7,7 +7,8 @@ import pytest
 from repro.core import fabric as F
 from repro.core import metrics as M
 from repro.core.arena import ArenaError, ArenaRegistry, IsolationError, TenantArena
-from repro.core.backend import NexusBackend
+from repro.core.backend import BackendCrashed, NexusBackend
+from repro.core.cache import CacheSpec, SharedCache
 from repro.core.credentials import CredentialError, TokenManager
 from repro.core.frontend import GuestContext, NexusClient
 from repro.core.hints import (InputHint, OutputHint, extract_hints,
@@ -19,6 +20,7 @@ from repro.core.storage import FaultPlan, ObjectStore, RemoteStorage
 from repro.core.streaming import CircularBuffer
 from repro.core.supervisor import Supervisor
 
+MB = 1024 * 1024
 
 def make_backend(transport="tcp", **kw):
     store = ObjectStore()
@@ -63,6 +65,36 @@ class TestArena:
         slot = arena.alloc(16)
         with pytest.raises(ArenaError):
             slot.write(b"y" * 17)
+
+    def test_write_releases_the_gil(self):
+        """A thread spinning while `Slot.write` copies 128 MB sees the
+        destination half written: it ran during the copy, which a copy
+        holding the GIL never lets happen."""
+        n, step = 128 * MB, 4 * MB
+        arena = TenantArena("t", capacity_mb=n / MB)
+        slot = arena.alloc(n)
+        dst = slot.arena._buf_view[::step]
+        done = threading.Event()
+        seen = []
+
+        def spin():
+            while not done.is_set():
+                if len(set(bytes(dst))) > 1:     # some samples old, some new
+                    seen.append(1)
+
+        t = threading.Thread(target=spin)
+        t.start()
+        try:
+            for v in range(1, 5):
+                src = bytes([v]) * n
+                slot.write(src)
+                assert bytes(dst) == bytes([v]) * (n // step)
+                if seen:
+                    break
+        finally:
+            done.set()
+            t.join()
+        assert seen
 
 
 # ------------------------------------------------------------- control plane
@@ -243,6 +275,21 @@ class TestBackend:
         # strictly above what the old nbytes=0 bug billed
         assert charged > F.remoted_op_cost("aws", 0).total()
 
+    def test_prefetch_hit_is_one_direct_copy(self):
+        store, acct, be = make_backend()
+        be.cache = SharedCache(CacheSpec(capacity_mb=1.0))
+        store.put("in", "obj", b"q" * 4096)
+        cred = be.register_function("fn", {"in"})
+        first = be.prefetch("fn", cred, InputHint("in", "obj", 4096)).wait()
+        assert be.stats["cache_hits"] == 0
+        slot = be.prefetch("fn", cred, InputHint("in", "obj", 4096)).wait()
+        assert slot is not first and slot.arena is be.arenas.get("fn")
+        assert bytes(slot.view()) == b"q" * 4096
+        assert be.stats["cache_hits"] == 1
+        snap = be.cache.snapshot()
+        assert (snap["hits"], snap["direct_hits"],
+                snap["copied_hits"]) == (1, 1, 0)
+
     def test_unauthorized_bucket_denied(self):
         store, acct, be = make_backend()
         store.put("secrets", "x", b"nope")
@@ -293,6 +340,49 @@ class TestCrashRecovery:
             obj = client.get_object(Bucket="in", Key="obj")
             assert bytes(obj["Body"]) == b"p" * 1024
             assert sup.restarts >= 1
+        finally:
+            sup.stop()
+
+
+    def test_crash_between_hit_lookup_and_copy_leaves_no_pin(self):
+        """The backend dies after the hit pinned its payload and before
+        the copy (in the tenant slot's allocation): the prefetch fails,
+        the pin goes, no slot leaks, and the restarted backend serves
+        the same hit."""
+        store = ObjectStore()
+        acct = M.CycleAccount()
+        remote = RemoteStorage(store, "tcp", acct)
+        from repro.core.arena import ArenaRegistry
+        from repro.core.credentials import TokenManager
+        arenas, tokens = ArenaRegistry(), TokenManager()
+        cache = SharedCache(CacheSpec(capacity_mb=1.0))
+        sup = Supervisor(lambda: NexusBackend(remote, acct, arenas=arenas,
+                                              tokens=tokens, cache=cache))
+        sup.start()
+        try:
+            store.put("in", "obj", b"p" * 4096)
+            cred = sup.backend.register_function("fn", {"in"})
+            hint = InputHint("in", "obj", 4096)
+            sup.backend.prefetch("fn", cred, hint).wait().release()
+            arena = arenas.get("fn")
+            real_alloc_wait = arena.alloc_wait
+
+            def dying_alloc_wait(size, timeout_s=10.0):
+                assert cache._pins                # the hit is pinned
+                sup.kill_backend()
+                raise BackendCrashed("nexus backend is down")
+
+            arena.alloc_wait = dying_alloc_wait
+            with pytest.raises(BackendCrashed):
+                sup.backend.prefetch("fn", cred, hint).wait()
+            assert cache._pins == {} and arena.allocated == 0
+            arena.alloc_wait = real_alloc_wait
+            deadline = time.monotonic() + 2.0
+            while not sup.backend.alive and time.monotonic() < deadline:
+                time.sleep(0.005)
+            slot = sup.backend.prefetch("fn", cred, hint).wait()
+            assert bytes(slot.view()) == b"p" * 4096
+            assert cache.snapshot()["direct_hits"] == 2
         finally:
             sup.stop()
 

@@ -151,6 +151,8 @@ def test_cache_hit_copies_are_spanned_with_their_bytes():
     warm = _spans_of(res[1].invocation_id, t0)
     gets = [s for s in warm if s.name == "nexus.cache.get"]
     assert any(s.attrs["bytes"] > 0 for s in gets)
+    # the served path's hits copy straight from the parked payload
+    assert all(s.attrs["direct"] for s in gets)
     writes = [s for s in warm if s.name == "nexus.arena.write"]
     assert writes and all(s.attrs["bytes"] > 0 for s in writes)
     hits = [s for s in warm
