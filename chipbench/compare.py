@@ -61,4 +61,6 @@ def kv_numbers(pairs) -> dict:
     return {"kv_rel_err": worst, "kv_index_err": float(bad)}
 
 
+#: a mix's ``compare`` kind -> the function that reads its numbers; the
+#: kind also names what the reference returns (``forward``'s ``want``)
 NUMBERS = {"logits": logits_numbers, "kv": kv_numbers}

@@ -3,13 +3,18 @@
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``configs/<name>.json``) and a traffic mix (``traffic/<name>.json``);
 its limits are in ``limits/<cell>.json`` and each of its metrics is read
-by ``metrics/<metric>.py``. All are found by name: adding a cell, a mix
-or a metric adds files and entries and edits none.
+by ``metrics/<metric>.py``. The configuration names its architecture by
+its ``reference`` key: the program's config, the weights' leaves, the
+operation count and the plain reference come from ``arch/<arch>.py``
+and ``reference/<arch>.py`` (`architectures`). All are found by name:
+adding a cell, a mix, a metric or an architecture adds files and entries
+and edits none.
 
 One run:
 
-1. refuses to go on without the program's code, a TPU, enough chips and
-   a ``device_kind`` in ``peaks.json``;
+1. refuses to go on without the configuration's architecture, the
+   program's code, a TPU, enough chips and a ``device_kind`` in
+   ``peaks.json``;
 2. set-up: makes the weights on the device from the seed
    (`reference.weights`), serializes them with the program's codec and
    stages them in an ``ObjectStore`` with the first input; deploys the
@@ -23,9 +28,9 @@ One run:
    every durable output back from the store once its response has
    arrived, and with ``--trace 1`` records a profiler trace of the
    window;
-4. the check: after the node is shut down, the reference recomputes a
-   sample of the window's answers, drawn from the seed, and `compare`
-   holds each number to the cell's limit.
+4. the check: after the node is shut down, the architecture's reference
+   recomputes a sample of the window's answers, drawn from the seed, and
+   `compare` holds each number to the cell's limit.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the end-to-end metrics, or with
@@ -40,7 +45,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import resource
@@ -48,6 +52,8 @@ import sys
 import time
 
 import numpy as np
+
+from chipbench import architectures
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -122,6 +128,7 @@ class Cell:
     limits: dict            # limits/<cell>.json: number -> {"limit", ...}
     metrics: list           # BENCHMARK.json entries this cell reports
     peaks: dict             # peaks.json
+    arch: architectures.Architecture    # the one spec["reference"] names
 
 
 def _json(path: str) -> dict:
@@ -130,6 +137,8 @@ def _json(path: str) -> dict:
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
+    """Cell `name` of ``<root>/BENCHMARK.json``, with its configuration's
+    architecture found under ``<root>/chipbench``."""
     bench = _json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -146,13 +155,18 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         m["trace"] = 0
     for m in per_layer:
         m["trace"] = 1
-    return Cell(name=name, chips=w["chips"],
-                spec=_json(os.path.join(root, conf["file"])),
+    spec = _json(os.path.join(root, conf["file"]))
+    try:
+        arch = architectures.find(spec.get("reference"),
+                                  os.path.join(root, "chipbench"))
+    except architectures.Unknown as e:
+        raise Refused(2, f"{conf['name']}: {e}")
+    return Cell(name=name, chips=w["chips"], spec=spec,
                 mix=_json(os.path.join(HERE, "traffic",
                                        f"{w['traffic']}.json")),
                 limits=_json(os.path.join(HERE, "limits", f"{name}.json")),
                 metrics=e2e + per_layer,
-                peaks=_json(os.path.join(HERE, "peaks.json")))
+                peaks=_json(os.path.join(HERE, "peaks.json")), arch=arch)
 
 
 def import_program(root: str = ROOT) -> None:
@@ -179,21 +193,10 @@ def check_device(chips: int, peaks: dict) -> dict:
     return {"platform": devs[0].platform, "kind": kind, "count": len(devs)}
 
 
-def program_config(spec: dict):
-    """The program's `ModelConfig` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if spec["attention_bias"] or spec["mlp_bias"]:
-        raise Refused(2, f"{spec['name']}: the served model has no biases")
-    return ModelConfig(
-        name=spec["name"], family="dense",
-        num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
-        num_heads=spec["num_attention_heads"],
-        num_kv_heads=spec["num_key_value_heads"],
-        d_ff=spec["intermediate_size"], vocab_size=spec["vocab_size"],
-        head_dim=spec["head_dim"], rope_theta=float(spec["rope_theta"]),
-        norm_eps=spec["rms_norm_eps"],
-        tie_embeddings=spec["tie_word_embeddings"],
-        dtype=spec["torch_dtype"], param_dtype=spec["torch_dtype"])
+def program_config(spec: dict, arch=None):
+    """The program's `ModelConfig` for a configuration file, as `arch`
+    (by default the one the file names) builds it."""
+    return architectures.of(spec, arch).arch.program_config(spec)
 
 
 def program_tree(flat: dict, struct):
@@ -288,7 +291,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     from chipbench.reference import weights
 
     mix = cell.mix
-    cfg = program_config(cell.spec)
+    cfg = program_config(cell.spec, cell.arch)
     w = ml_suite_at({mix["role"]: cfg})[mix["scenario"]]
     structs = serving.bundle(cfg)["structs"]
     gen = loadgen.Mix(mix, cell.spec, seed)
@@ -297,7 +300,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
                          f"{structs[mix['input']].shape}, the mix sends "
                          f"{gen.shape}")
     with CompileLog() as clog:
-        params = program_tree(weights.make(cell.spec, seed),
+        params = program_tree(weights.make(cell.spec, seed, cell.arch),
                               structs["params"])
         blob = serialize.dumps(params)
         del params
@@ -371,29 +374,26 @@ def check(cell: Cell, seed: int, run: dict, control: bool = False) -> dict:
     import jax
     from repro.models import serialize
 
-    from chipbench import compare
-    from chipbench.reference import llama, weights
+    from chipbench.reference import weights
 
     mix, gen = cell.mix, run["gen"]
+    arch = cell.arch
     ok = [r for r in run["invocations"] if r["ok"]]
     sample = [ok[j] for j in gen.sample(len(ok))]
-    want = ("kv",) if mix["compare"] == "kv" else ("logits",)
-    numbers = compare.NUMBERS[mix["compare"]]
-    w = weights.make(cell.spec, seed)
+    want = (mix["compare"],)
+    numbers = arch.numbers(mix["compare"])
+    w = weights.make(cell.spec, seed, arch)
     pairs, ctl_pairs = [], []
     for r in sample:
         got = jax.tree.map(np.asarray, serialize.loads(
             run["structs"][mix["output"]], r["body"]))
         tokens = gen.tokens(r["index"])
-        ref = llama.forward(cell.spec, w, tokens, want=want)
+        ref = arch.reference.forward(cell.spec, w, tokens, want=want)
         pairs.append(_pair(mix, got, ref))
         if control:
-            low = llama.forward(cell.spec, w, tokens, want=want,
-                                precision="float8_e4m3fn")
-            ctl = dict(low, pos=np.full(tokens.shape[:1], tokens.shape[1]),
-                       slot_pos=np.broadcast_to(np.arange(tokens.shape[1]),
-                                                tokens.shape))
-            ctl_pairs.append(_pair(mix, ctl, ref))
+            low = arch.reference.forward(cell.spec, w, tokens, want=want,
+                                         precision="float8_e4m3fn")
+            ctl_pairs.append(_pair(mix, _as_served(low, tokens), ref))
     del w
     out = {"program": numbers(pairs) if pairs else {},
            "sample": [r["index"] for r in sample]}
@@ -402,8 +402,16 @@ def check(cell: Cell, seed: int, run: dict, control: bool = False) -> dict:
     return out
 
 
+def _as_served(out: dict, tokens) -> dict:
+    """A reference's output in the form the program serves it: with the
+    position counters of a cache that holds the whole prompt in order."""
+    return dict(out, pos=np.full(tokens.shape[:1], tokens.shape[1]),
+                slot_pos=np.broadcast_to(np.arange(tokens.shape[1]),
+                                         tokens.shape))
+
+
 def _pair(mix: dict, got, ref: dict):
-    if mix["compare"] == "kv":
+    if mix["compare"] != "logits":
         return got, ref
     return (got["logits"] if isinstance(got, dict) else got), ref["logits"]
 
@@ -411,12 +419,9 @@ def _pair(mix: dict, got, ref: dict):
 # ------------------------------------------------------------ metrics
 
 def _reader(name: str):
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return architectures.load_module(
+        os.path.join(HERE, "metrics", f"{name}.py"),
+        f"chipbench_metric_{name.replace('.', '_')}").read
 
 
 def read_metrics(cell: Cell, run: dict, trace: bool) -> dict:
@@ -439,7 +444,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     run = serve(cell, seed, seconds, trace, t0, log=log)
     run["peaks"] = cell.peaks["devices"][device["kind"]]
     run["flops_per_step"] = flops.prefill_flops(
-        cell.spec, cell.mix["batch"], cell.mix["seq"])["total"]
+        cell.spec, cell.mix["batch"], cell.mix["seq"], cell.arch)["total"]
     run["step_program"] = STEP_PROGRAM
     run["trace"] = None
     dev = dict(device, memory_peak_bytes=run["memory_peak_bytes"])

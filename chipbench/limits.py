@@ -7,9 +7,10 @@ From the root of a checkout, on a machine with the cell's chips::
 
 In one process, for each seed in turn: the cell's set-up and a window
 of ``--seconds`` (long enough to complete as many answers as a run
-checks), then the check, which also runs the control (the reference in
-float8, `reference.llama`) on the same prompts. Each seed prints one
-JSON line with the program's numbers and the control's; the last line
+checks), then the check, which also runs the control on the same
+prompts: the reference of the configuration's own architecture
+(``reference/<arch>.py``) in float8. Each seed prints one JSON line
+with the program's numbers and the control's; the last line
 gives, per number, the largest the program read (the lower reading) and
 the smallest the control read (the upper one). The benchmark's own runs
 never run the control.

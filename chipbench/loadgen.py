@@ -10,7 +10,8 @@ A mix's keys:
   invocations, ``scale_to_zero`` drops it after every response, so each
   invocation restores a fresh one;
 * ``output``, ``compare``: the program's name for the durable output's
-  shape tree, and which numbers of `compare` judge it;
+  shape tree, and the kind of numbers that judge it: one of `compare`'s,
+  or of the architecture's reference (`architectures`);
 * ``check_sample``: how many of a window's answers the reference
   recomputes, drawn from the seed.
 

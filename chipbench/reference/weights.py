@@ -1,13 +1,16 @@
-"""Seeded weights of a llama-architecture configuration, made on the device.
+"""Seeded weights of a configuration, made on the device.
 
 The benchmark, not the system under test, makes the weights: the harness
 uploads them (the client's checkpoint) and the plain reference makes the
 same values again from the same seed, so the reference takes nothing the
 program made.
 
-Leaves are named by the checkpoint layout the served program reads
-(``layers.attn.wq`` is the stacked (L, D, H*hd) query projection), one
-name per array. Every value is uniform, drawn from 16 random bits per
+Which leaves there are, by name and shape, and which of them are norm
+scales, is the architecture's to say (``arch/<arch>.py`` ``shapes`` and
+``is_norm``, found by `architectures` from the configuration's
+``reference`` key); the names are the checkpoint layout the served
+program reads, one name per array. The rest holds for every
+architecture: every value is uniform, drawn from 16 random bits per
 element, with the spread of a LeCun initialisation for a matrix and
 1 +- 0.2 for a norm scale. The bits come from threefry, an integer
 computation, so a value depends only on the seed and the leaf.
@@ -21,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import architectures
+
 #: a norm scale is 1 + NORM_SPREAD * u with u uniform in (-1, 1)
 NORM_SPREAD = 0.2
 
@@ -31,28 +36,10 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(int(word))
 
 
-def shapes(spec: dict) -> dict[str, tuple[int, ...]]:
-    """Leaf name -> shape, in sorted name order."""
-    L = spec["num_hidden_layers"]
-    D = spec["hidden_size"]
-    H, K = spec["num_attention_heads"], spec["num_key_value_heads"]
-    hd, F, V = spec["head_dim"], spec["intermediate_size"], spec["vocab_size"]
-    out = {
-        "embed": (V, D),
-        "final_norm": (D,),
-        "layers.attn.wk": (L, D, K * hd),
-        "layers.attn.wo": (L, H * hd, D),
-        "layers.attn.wq": (L, D, H * hd),
-        "layers.attn.wv": (L, D, K * hd),
-        "layers.ln1": (L, D),
-        "layers.ln2": (L, D),
-        "layers.mlp.w_down": (L, F, D),
-        "layers.mlp.w_gate": (L, D, F),
-        "layers.mlp.w_up": (L, D, F),
-    }
-    if not spec["tie_word_embeddings"]:
-        out["lm_head"] = (D, V)
-    return dict(sorted(out.items()))
+def shapes(spec: dict, arch=None) -> dict[str, tuple[int, ...]]:
+    """Leaf name -> shape, in sorted name order, by `arch` (by default
+    the architecture `spec` names)."""
+    return dict(sorted(architectures.of(spec, arch).arch.shapes(spec).items()))
 
 
 def _fan_in(name: str, shape: tuple[int, ...]) -> int:
@@ -61,10 +48,10 @@ def _fan_in(name: str, shape: tuple[int, ...]) -> int:
     return shape[-1] if name == "embed" else shape[-2]
 
 
-def _leaf(key, name: str, shape: tuple[int, ...], dtype):
+def _leaf(key, name: str, shape: tuple[int, ...], norm: bool, dtype):
     bits = jax.random.bits(key, shape, jnp.uint16)
     u = (bits.astype(jnp.float32) + 0.5) * (2.0 / 65536.0) - 1.0
-    if len(shape) == 1 or name.endswith(("ln1", "ln2")):
+    if norm:
         return (1.0 + NORM_SPREAD * u).astype(dtype)
     return (u * (math.sqrt(3.0) / math.sqrt(_fan_in(name, shape)))
             ).astype(dtype)
@@ -73,15 +60,18 @@ def _leaf(key, name: str, shape: tuple[int, ...], dtype):
 @functools.lru_cache(maxsize=None)
 def _maker(items: tuple, dtype: str):
     def make(key):
-        return {n: _leaf(jax.random.fold_in(key, i), n, s, jnp.dtype(dtype))
-                for i, (n, s) in enumerate(items)}
+        return {n: _leaf(jax.random.fold_in(key, i), n, s, norm,
+                         jnp.dtype(dtype))
+                for i, (n, s, norm) in enumerate(items)}
     return jax.jit(make)
 
 
-def make(spec: dict, seed: int) -> dict:
+def make(spec: dict, seed: int, arch=None) -> dict:
     """Every leaf of `spec`'s weights from `seed`, in one jitted call, in
     the dtype the configuration serves (``torch_dtype``)."""
-    items = tuple(shapes(spec).items())
+    arch = architectures.of(spec, arch)
+    items = tuple((n, s, bool(arch.arch.is_norm(n, s)))
+                  for n, s in shapes(spec, arch).items())
     return _maker(items, spec["torch_dtype"])(seed_key(seed))
 
 

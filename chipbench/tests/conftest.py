@@ -5,8 +5,10 @@ Run them by path from the root of the checkout::
     python -m pytest -q chipbench/tests
 
 The cells run here at each configuration's SMOKE size: every width cut
-as the program's own SMOKE configurations cut them, and a name ending
-in ``-smoke`` so the program serves its tiny shapes.
+as the program's own SMOKE configurations cut them (``smoke/<config>.json``),
+and a name ending in ``-smoke`` so the program serves its tiny shapes.
+The cells and configurations are those of ``BENCHMARK.json``: a new one
+adds its files, and the tests that hold for any of them take it.
 """
 import os
 import sys
@@ -19,36 +21,47 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import json  # noqa: E402
+
 import pytest  # noqa: E402
 
 from chipbench import harness  # noqa: E402
 
-#: the program's SMOKE widths of each configuration's model
-SMOKE = {
-    "yi-34b-4l": dict(hidden_size=112, num_attention_heads=7,
-                      num_key_value_heads=1, head_dim=16,
-                      intermediate_size=224, vocab_size=512,
-                      num_hidden_layers=2),
-}
-#: the program's tiny serving shapes (``calibrate.SERVING_SHAPES``)
-TINY = {"enc_tokens": (4, 16), "prompt": (1, 32)}
-CELLS = ("yi-emb-warm", "yi-prefill-coldvm")
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+#: every cell and every configuration of ``BENCHMARK.json``
+CELLS = tuple(w["name"] for w in BENCH["workloads"])
+CONFIGS = tuple(c["name"] for c in BENCH["configs"])
 
 FAKE_TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 
 
+def config_spec(config: str) -> dict:
+    """Configuration `config`'s file as the cells run it."""
+    conf = {c["name"]: c for c in BENCH["configs"]}[config]
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        return json.load(f)
+
+
 def smoke_spec(spec: dict) -> dict:
-    return dict(spec, name=spec["name"] + "-smoke", **SMOKE[spec["name"]])
+    """`spec` at the program's SMOKE widths of its model
+    (``smoke/<config>.json``)."""
+    with open(os.path.join(os.path.dirname(__file__), "smoke",
+                           f"{spec['name']}.json")) as f:
+        return dict(spec, name=spec["name"] + "-smoke", **json.load(f))
 
 
-def smoke_cell(name: str) -> "harness.Cell":
-    """`name`'s cell at its configuration's SMOKE size: tiny shapes,
-    and every answer of the window checked."""
-    cell = harness.load_cell(name)
-    shape = TINY[cell.mix["input"]]
+def smoke_cell(name: str, root: str = ROOT) -> "harness.Cell":
+    """`name`'s cell at its configuration's SMOKE size: the program's
+    tiny serving shape of the mix's input, and every answer of the
+    window checked."""
+    from repro.models import serving
+    cell = harness.load_cell(name, root)
     cell.spec = smoke_spec(cell.spec)
-    cell.mix = dict(cell.mix, batch=shape[0], seq=shape[1],
-                    check_sample=100)
+    structs = serving.bundle(
+        harness.program_config(cell.spec, cell.arch))["structs"]
+    batch, seq = structs[cell.mix["input"]].shape
+    cell.mix = dict(cell.mix, batch=batch, seq=seq, check_sample=100)
     return cell
 
 

@@ -1,39 +1,36 @@
 """Operation and parameter counts of the cells' configurations."""
-import json
-
 import jax
 import pytest
 
 from chipbench import flops, harness
 from chipbench.reference import weights
+from chipbench.tests.conftest import CELLS, CONFIGS, config_spec
 
-#: parameters of each configuration as served, and its bf16 bytes
-PARAMS = {"yi-34b-4l": 3_148_938_240}
-#: (configuration, (batch, sequence)) of each cell's step
-CELL_SHAPES = [("yi-34b-4l", (32, 512)), ("yi-34b-4l", (1, 2048))]
-
-
-def _spec(config):
-    with open(f"{harness.ROOT}/chipbench/configs/{config}.json") as f:
-        return json.load(f)
+#: (configuration, (batch, sequence)) of each cell's step whose
+#: configuration is a llama, the architecture the program's own
+#: `models/flops.py` counts
+CELL_SHAPES = [(c.spec["name"], (c.mix["batch"], c.mix["seq"]))
+               for c in map(harness.load_cell, CELLS)
+               if c.spec["reference"] == "llama"]
 
 
-@pytest.mark.parametrize("config", sorted(PARAMS))
+@pytest.mark.parametrize("config", CONFIGS)
 def test_parameter_counts(config):
     from repro.models import get_model
-    spec = _spec(config)
+    spec = config_spec(config)
     model = get_model(harness.program_config(spec))
     struct = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     n = sum(leaf.size for leaf in jax.tree.leaves(struct))
-    assert n == PARAMS[config] == weights.param_count(spec)
-    assert weights.nbytes(spec) == 2 * PARAMS[config]
+    assert n == weights.param_count(spec)
+    item = jax.numpy.dtype(spec["torch_dtype"]).itemsize
+    assert weights.nbytes(spec) == item * n
 
 
 @pytest.mark.parametrize("config,shape", CELL_SHAPES)
 def test_flops_agree_with_the_programs_count(config, shape):
     from repro.configs.base import InputShape
     from repro.models.flops import model_flops
-    spec = _spec(config)
+    spec = config_spec(config)
     cfg = harness.program_config(spec)
     B, S = shape
     ours = flops.prefill_flops(spec, B, S)
@@ -49,7 +46,7 @@ def test_flops_agree_with_the_programs_count(config, shape):
 
 
 def test_cell_step_flops():
-    emb = flops.prefill_flops(_spec("yi-34b-4l"), 32, 512)["total"]
-    prefill = flops.prefill_flops(_spec("yi-34b-4l"), 1, 2048)["total"]
+    emb = flops.prefill_flops(config_spec("yi-34b-4l"), 32, 512)["total"]
+    prefill = flops.prefill_flops(config_spec("yi-34b-4l"), 1, 2048)["total"]
     assert 73.62e12 < emb < 73.63e12
     assert 9.38e12 < prefill < 9.39e12

@@ -1,18 +1,19 @@
-"""The plain reference against the program's own prefill, at SMOKE size.
+"""Each architecture's plain reference against the program's own prefill,
+at SMOKE size.
 
 In float32 the two are the same mathematics and agree to rounding; in
 the bfloat16 the configurations serve, the program stays inside each
 cell's limit, and the float8 control does not.
 """
-import json
-
 import jax
 import numpy as np
 import pytest
 
-from chipbench import compare, harness
-from chipbench.reference import llama, weights
-from chipbench.tests.conftest import CELLS, smoke_cell, smoke_spec
+from chipbench import architectures, harness
+from chipbench.compare import rel_err
+from chipbench.reference import weights
+from chipbench.tests.conftest import (CELLS, CONFIGS, config_spec,
+                                      smoke_cell, smoke_spec)
 
 SEEDS = (3, 2**31 + 5)
 
@@ -32,22 +33,18 @@ def _tokens(spec, shape, seed):
         0, spec["vocab_size"], shape, dtype=np.int32)
 
 
-def _spec(config):
-    with open(f"{harness.ROOT}/chipbench/configs/{config}.json") as f:
-        return smoke_spec(json.load(f))
-
-
 @pytest.mark.parametrize("seed", SEEDS)
-@pytest.mark.parametrize("config", ["yi-34b-4l"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_float32_program_matches_reference(config, seed):
-    spec = dict(_spec(config), torch_dtype="float32")
+    spec = dict(smoke_spec(config_spec(config)), torch_dtype="float32")
     tokens = _tokens(spec, (2, 24), seed)
     logits, cache = _program(spec, seed, tokens)
-    ref = llama.forward(spec, weights.make(spec, seed), tokens,
-                        want=("logits", "kv"), row_block=1)
-    assert compare.rel_err(logits, ref["logits"]) < 1e-4
+    ref = architectures.of(spec).reference.forward(
+        spec, weights.make(spec, seed), tokens, want=("logits", "kv"),
+        row_block=1)
+    assert rel_err(logits, ref["logits"]) < 1e-4
     for name in ("k", "v"):
-        assert compare.rel_err(cache[name], ref[name]) < 1e-4
+        assert rel_err(cache[name], ref[name]) < 1e-4
 
 
 @pytest.mark.parametrize("seed", SEEDS)
@@ -58,11 +55,12 @@ def test_served_precision_inside_limit_and_control_outside(name, seed):
     tokens = _tokens(spec, (mix["batch"], mix["seq"]), seed)
     logits, cache = _program(spec, seed, tokens)
     w = weights.make(spec, seed)
-    want = ("kv",) if mix["compare"] == "kv" else ("logits",)
-    ref = llama.forward(spec, w, tokens, want=want)
-    low = llama.forward(spec, w, tokens, want=want,
-                        precision="float8_e4m3fn")
-    numbers = compare.NUMBERS[mix["compare"]]
+    arch = architectures.of(spec)
+    want = (mix["compare"],)
+    ref = arch.reference.forward(spec, w, tokens, want=want)
+    low = arch.reference.forward(spec, w, tokens, want=want,
+                                 precision="float8_e4m3fn")
+    numbers = arch.numbers(mix["compare"])
     if mix["compare"] == "kv":
         served = numbers([(cache, ref)])
         ctl = dict(low, pos=cache["pos"], slot_pos=cache["slot_pos"])

@@ -7,9 +7,12 @@ import sys
 from types import SimpleNamespace
 
 import jax
+import numpy as np
 import pytest
 
 from chipbench import harness
+from chipbench.reference import weights
+from chipbench.tests.conftest import CELLS, smoke_cell
 
 
 def test_cpu_device_is_refused():
@@ -73,6 +76,20 @@ def test_benchmark_file_names_what_the_harness_finds():
         for m in cell.metrics:
             if m["trace"] == 1:
                 assert m["moves"] in names, (w["name"], m["name"])
-        assert set(cell.limits) == set(
-            {"logits": ["logits_rel_err"],
-             "kv": ["kv_rel_err", "kv_index_err"]}[cell.mix["compare"]])
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_limits_name_every_compared_number(name):
+    """The cell's limits are those of the numbers its check reads: the
+    reference's answer, in the form the program serves it, against
+    itself."""
+    cell = smoke_cell(name)
+    spec, mix = cell.spec, cell.mix
+    tokens = np.zeros((mix["batch"], mix["seq"]), np.int32)
+    ref = cell.arch.reference.forward(spec, weights.make(spec, 1, cell.arch),
+                                      tokens, want=(mix["compare"],))
+    got = harness._as_served(ref, tokens)
+    numbers = cell.arch.numbers(mix["compare"])(
+        [harness._pair(mix, got, ref)])
+    assert set(numbers) == set(cell.limits)
+    assert all(v == 0 for v in numbers.values()), numbers
