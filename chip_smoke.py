@@ -69,13 +69,13 @@ def _direct(name: str, payloads: list, cfg):
     if name == "LLM-COLD":
         params = serialize.loads(st["params"], b"".join(payloads[:-1]))
         tokens = serialize.loads(st["prompt"], payloads[-1])
-        logits, cache = b["prefill"](params, {"tokens": tokens})
+        logits, cache, _ = b["prefill"](params, {"tokens": tokens})
         logits, _ = b["decode"](params, cache, serving.next_token(logits))
         durable = serialize.dumps(logits)
     elif name == "LLM-PREFILL":
         params = serialize.loads(st["params"], payloads[0])
         tokens = serialize.loads(st["prompt"], payloads[1])
-        logits, cache = b["prefill"](params, {"tokens": tokens})
+        logits, cache, _ = b["prefill"](params, {"tokens": tokens})
         durable = serialize.dumps(cache)
     elif name == "LLM-DECODE":
         params = serialize.loads(st["params"], payloads[0])
@@ -86,7 +86,7 @@ def _direct(name: str, payloads: list, cfg):
     elif name == "EMB":
         params = serialize.loads(st["params"], payloads[0])
         tokens = serialize.loads(st["enc_tokens"], payloads[1])
-        logits, _ = b["prefill"](params, {"tokens": tokens})
+        logits, _, _ = b["prefill"](params, {"tokens": tokens})
         durable = serialize.dumps(logits)
     else:
         raise ValueError(name)

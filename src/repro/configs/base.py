@@ -37,12 +37,13 @@ class ModelConfig:
     sliding_window: int = 0         # 0 -> full attention
     rope_theta: float = 10_000.0
 
-    # MoE
-    num_experts: int = 0
+    # MoE (see models/moe.py)
+    num_experts: int = 0            # the router's width
     num_experts_per_tok: int = 0
-    capacity_factor: float = 1.25
+    held_experts: int = 0           # experts this layer computes; 0 -> all
+    first_expert: int = 0           # router index of the first one held
+    router_dtype: str = "float32"   # the router weight's stored dtype
     router_aux_loss: float = 0.001  # load-balance loss weight
-    moe_impl: str = "sorted"        # sorted | dense (see models/moe.py)
 
     # SSM (Mamba-1)
     ssm_state: int = 0
@@ -73,6 +74,15 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.family in ("ssm", "hybrid") and self.dt_rank == 0:
             object.__setattr__(self, "dt_rank", -(-self.d_model // 16))
+        first, n = self.expert_share
+        if self.held_experts < 0 or first < 0 or first + n > self.num_experts:
+            raise ValueError(f"{self.name}: experts {first}..{first + n - 1} "
+                             f"are not among the router's {self.num_experts}")
+
+    @property
+    def expert_share(self) -> tuple[int, int]:
+        """(first expert, how many) of the experts an expert layer holds."""
+        return self.first_expert, self.held_experts or self.num_experts
 
     @property
     def d_inner(self) -> int:

@@ -122,8 +122,9 @@ def forward_train(cfg, params, batch):
     return loss, {"ce_loss": loss, "aux_loss": jnp.zeros((), jnp.float32)}
 
 
-def prefill(cfg, params, batch, cache_len=None):
-    """Encode source + teacher-forced decoder prefill; build decode cache."""
+def prefill(cfg, params, batch, cache_len=None, counters=False):
+    """Encode source + teacher-forced decoder prefill; build decode cache.
+    `counters`: as `lm.prefill`'s; this family counts nothing."""
     enc_out = encode(cfg, params, batch["src_embeds"])
     tgt = batch["tgt_tokens"]
     B, St = tgt.shape
@@ -140,7 +141,7 @@ def prefill(cfg, params, batch, cache_len=None):
         "slot_pos": kvc.prefill_slot_pos(St, W, B),
         "cross_k": caches["cross_k"], "cross_v": caches["cross_v"],
     }
-    return logits, cache
+    return (logits, cache, {}) if counters else (logits, cache)
 
 
 def decode_step(cfg, params, cache, token):

@@ -17,6 +17,10 @@ from repro.models import mamba as M
 from repro.models import moe as MOE
 
 LOSS_CHUNK = 2048
+#: the moe family's per-layer counter: how many assignments each held
+#: expert computed. It leaves the layer scan beside the cache entries and
+#: `prefill` takes it out of the cache.
+EXPERT_TOKENS = "expert_tokens"
 
 
 # ------------------------------------------------------------------- params
@@ -94,14 +98,11 @@ def _seq_sublayers(cfg, lp, x, mode, ssm_state=None, cache_len=0):
 
     h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
     if fam == "moe":
-        if cfg.moe_impl == "local" and mode == "train":
-            # XLA 0.8 CHECK-crash ("Invalid binary instruction opcode
-            # copy") when differentiating shard_map+checkpoint bodies;
-            # training keeps the hinted global dispatch until fixed.
-            moe_out, aux = MOE.moe_sorted(lp["moe"], cfg, h2)
-        else:
-            moe_out, aux = MOE.moe_layer(lp["moe"], cfg, h2)
+        with jax.named_scope("moe"):
+            moe_out, aux, tokens = MOE.moe_layer(lp["moe"], cfg, h2)
         x = x + moe_out
+        if mode == "prefill":
+            cache_out[EXPERT_TOKENS] = tokens
     else:
         x = x + L.mlp_layer(lp["mlp"], h2)
     return x, cache_out, aux
@@ -263,8 +264,10 @@ def forward_train(cfg, params, batch):
     return total, {"ce_loss": loss, "aux_loss": aux}
 
 
-def prefill(cfg, params, batch, cache_len=None):
-    """Process the prompt; returns (last-token logits, decode cache)."""
+def prefill(cfg, params, batch, cache_len=None, counters=False):
+    """Process the prompt; returns (last-token logits, decode cache), and
+    with `counters` the step's counters as well: for the moe family
+    ``{"expert_tokens": (L, held) int32}``, else ``{}``."""
     if cfg.embed_input:
         x = batch["inputs_embeds"].astype(jnp.dtype(cfg.dtype))
         B, S = x.shape[:2]
@@ -273,6 +276,9 @@ def prefill(cfg, params, batch, cache_len=None):
         B, S = batch["tokens"].shape
     hidden, caches, _ = run_stack(cfg, params, x, "prefill",
                                   cache_len=cache_len or S)
+    step = {}
+    if EXPERT_TOKENS in caches:
+        step[EXPERT_TOKENS] = caches.pop(EXPERT_TOKENS)
     logits = (hidden[:, -1:] @ _lm_head(cfg, params)).astype(jnp.float32)
 
     cache = {"pos": jnp.full((B,), S, jnp.int32)}
@@ -280,7 +286,7 @@ def prefill(cfg, params, batch, cache_len=None):
     if cfg.family in ("dense", "vlm", "moe", "hybrid"):
         W = kvc.cache_width(cfg, max(cache_len or S, S))
         cache["slot_pos"] = kvc.prefill_slot_pos(S, W, B)
-    return logits, cache
+    return (logits, cache, step) if counters else (logits, cache)
 
 
 def decode_step(cfg, params, cache, token):

@@ -22,8 +22,9 @@ class Model:
     def loss(self, params, batch):
         return self._mod.forward_train(self.cfg, params, batch)
 
-    def prefill(self, params, batch, cache_len=None):
-        return self._mod.prefill(self.cfg, params, batch, cache_len=cache_len)
+    def prefill(self, params, batch, cache_len=None, counters=False):
+        return self._mod.prefill(self.cfg, params, batch,
+                                 cache_len=cache_len, counters=counters)
 
     def decode_step(self, params, cache, token):
         return self._mod.decode_step(self.cfg, params, cache, token)
@@ -43,8 +44,9 @@ def get_model(cfg: ModelConfig) -> Model:
 def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     """Parameter count from abstract shapes (no allocation).
 
-    active_only: count only top-k experts' share of MoE FFN params
-    (for MODEL_FLOPS = 6 * N_active * D rooflines).
+    active_only: count only the expert FFN params a token is expected
+    to touch, top-k of the router's experts, of which the layer holds
+    its share (for MODEL_FLOPS = 6 * N_active * D rooflines).
     """
     import math
 
@@ -52,7 +54,8 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     shapes = model.param_shapes()
     total = sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
     if active_only and cfg.num_experts:
-        # expert FFN leaves scale by k/E
+        # the held experts' FFN leaves scale by k/E: a token meets
+        # k * held/E of them on average
         def expert_bytes(tree):
             layers = tree["layers"]["moe"]
             return sum(math.prod(layers[k].shape)

@@ -130,6 +130,7 @@ def role_config(role: str, cfg=None):
 @functools.lru_cache(maxsize=None)
 def bundle(cfg):
     """cfg, model, shape structs and jitted prefill/decode of a config.
+    The jitted prefill also returns the step's counters (`lm.prefill`).
 
     Holds no parameters: at chip size a resident seed copy would sit on
     the device beside every invocation's decoded copy. Jits are cached
@@ -140,10 +141,14 @@ def bundle(cfg):
     from repro.models import get_model
 
     model = get_model(cfg)
+
+    def prefill(params, batch):     # the device program is ``jit_prefill``
+        return model.prefill(params, batch, counters=True)
+
     return {
         "cfg": cfg, "model": model,
         "structs": _structs_for(cfg),
-        "prefill": jax.jit(model.prefill),
+        "prefill": jax.jit(prefill),
         "decode": jax.jit(model.decode_step),
     }
 
@@ -179,7 +184,7 @@ def _seed_blobs(b: dict, with_kv: bool) -> tuple[bytes, bytes | None]:
         # calibration declare is derived from exactly this shape —
         # the prompt shape need not coincide), then serialize
         # (cache, next-token) — the decode GET payload
-        logits, cache = b["prefill"](
+        logits, cache, _ = b["prefill"](
             params, {"tokens": _prompt_tokens(b, "decode_tokens")})
         kv = serialize.dumps((cache, next_token(logits)))
     return serialize.dumps(params), kv
@@ -229,7 +234,8 @@ def seed_payloads(scenario: str, cfg=None) -> list[bytes]:
 # `serialize.loads` with its host-to-device copy), ``nexus.handler.step``
 # from the jitted call until the outputs the encode reads are ready,
 # ``nexus.handler.encode`` (`serialize.dumps`). The step waits only on
-# what the encode would wait on anyway.
+# what the encode would wait on anyway. A moe step's span also carries
+# its expert counters (`_count_experts`).
 
 def _decode(shapes, *bodies):
     with M.span("nexus.handler.decode",
@@ -241,6 +247,22 @@ def _decode(shapes, *bodies):
 def _ready(tree):
     import jax
     return jax.block_until_ready(tree)
+
+
+def _count_experts(span, counters) -> None:
+    """Record a prefill's expert counters on its step span: the
+    assignments the held experts computed (``expert_tokens``), the
+    busiest held expert's (``expert_tokens_max``), each summed over the
+    layers, and how many experts are held (``experts``). They come back
+    with the step's outputs: no second device call."""
+    import numpy as np
+    tokens = counters.get("expert_tokens")
+    if tokens is None:
+        return
+    tokens = np.asarray(tokens)                 # (layers, held)
+    span.attrs.update(expert_tokens=int(tokens.sum()),
+                      expert_tokens_max=int(tokens.max(axis=-1).sum()),
+                      experts=int(tokens.shape[-1]))
 
 
 def _encode(tree) -> bytes:
@@ -256,9 +278,10 @@ def llm_cold(shard_bodies, prompt_body, cfg=None) -> bytes:
     b = bundle(role_config("llm", cfg))
     params = _decode(b["structs"]["params"], *shard_bodies)
     tokens = _decode(b["structs"]["prompt"], prompt_body)
-    with M.span("nexus.handler.step"):
-        logits, cache = b["prefill"](params, {"tokens": tokens})
+    with M.span("nexus.handler.step") as s:
+        logits, cache, counters = b["prefill"](params, {"tokens": tokens})
         logits2, _ = _ready(b["decode"](params, cache, next_token(logits)))
+        _count_experts(s, counters)
     return _encode(logits2)
 
 
@@ -268,9 +291,10 @@ def llm_prefill(params_body, prompt_body, cfg=None) -> bytes:
     b = bundle(role_config("llm", cfg))
     params = _decode(b["structs"]["params"], params_body)
     tokens = _decode(b["structs"]["prompt"], prompt_body)
-    with M.span("nexus.handler.step"):
-        _, cache = b["prefill"](params, {"tokens": tokens})
+    with M.span("nexus.handler.step") as s:
+        _, cache, counters = b["prefill"](params, {"tokens": tokens})
         _ready(cache)
+        _count_experts(s, counters)
     return _encode(cache)
 
 
@@ -291,9 +315,10 @@ def emb_encode(params_body, tokens_body, cfg=None) -> bytes:
     b = bundle(role_config("emb", cfg))
     params = _decode(b["structs"]["params"], params_body)
     tokens = _decode(b["structs"]["enc_tokens"], tokens_body)
-    with M.span("nexus.handler.step"):
-        logits, _ = b["prefill"](params, {"tokens": tokens})
+    with M.span("nexus.handler.step") as s:
+        logits, _, counters = b["prefill"](params, {"tokens": tokens})
         _ready(logits)
+        _count_experts(s, counters)
     return _encode(logits)
 
 
@@ -302,7 +327,9 @@ def moe_infer(shard_bodies, cfg=None) -> bytes:
     shards, run the fixed prompt through the router + top-k experts."""
     b = bundle(role_config("moe", cfg))
     params = _decode(b["structs"]["params"], *shard_bodies)
-    with M.span("nexus.handler.step"):
-        logits, _ = b["prefill"](params, {"tokens": _prompt_tokens(b)})
+    with M.span("nexus.handler.step") as s:
+        logits, _, counters = b["prefill"](params,
+                                           {"tokens": _prompt_tokens(b)})
         _ready(logits)
+        _count_experts(s, counters)
     return _encode(logits)

@@ -166,35 +166,3 @@ class TestShardingRules:
                               cfg.num_heads * cfg.head_dim), fake)
         from jax.sharding import PartitionSpec as P
         assert spec == P(None, "data", "model")
-
-
-class TestMoELocalDispatch:
-    def test_local_matches_sorted_on_mesh(self):
-        """shard_map-local dispatch is exact vs the global sort."""
-        import numpy as np
-        from repro.configs import registry
-        from repro.models import moe as MOE
-
-        cfg = registry.get_smoke("mixtral-8x22b").replace(
-            capacity_factor=8.0)
-        rng = jax.random.PRNGKey(0)
-        p = MOE.init_moe(rng, cfg, jnp.float32)
-        x = jax.random.normal(rng, (4, 16, cfg.d_model), jnp.float32)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        with jax.set_mesh(mesh):
-            y1, a1 = jax.jit(lambda p, x: MOE.moe_sorted(p, cfg, x))(p, x)
-            y2, a2 = jax.jit(lambda p, x: MOE.moe_local(p, cfg, x))(p, x)
-        np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                                   atol=1e-5, rtol=1e-4)
-        assert float(a1) == pytest.approx(float(a2), rel=1e-6)
-
-    def test_local_falls_back_without_mesh_divisibility(self):
-        from repro.configs import registry
-        from repro.models import moe as MOE
-
-        cfg = registry.get_smoke("mixtral-8x22b")
-        rng = jax.random.PRNGKey(1)
-        p = MOE.init_moe(rng, cfg, jnp.float32)
-        x = jax.random.normal(rng, (1, 8, cfg.d_model), jnp.float32)
-        y, aux = MOE.moe_local(p, cfg, x)      # no mesh context -> sorted
-        assert y.shape == x.shape
